@@ -36,7 +36,7 @@ def rows_from_dir(dryrun_dir: str = DRYRUN_DIR, mesh: str = None,
         mf = _model_flops(rec["arch"], rec["shape"])
         hlo_total = r["hlo_flops_per_dev"] * rec["chips"]
         t_bound = max(r["t_compute_s"], r["t_memory_s"], r["t_collective_s"])
-        from repro.roofline.analysis import PEAK_FLOPS
+        from repro.roofline.analysis import peaks_for
         rows.append({
             "arch": rec["arch"], "shape": rec["shape"], "mesh": rec["mesh"],
             "tag": rec.get("tag", ""),
@@ -45,7 +45,8 @@ def rows_from_dir(dryrun_dir: str = DRYRUN_DIR, mesh: str = None,
             "t_collective_s": r["t_collective_s"],
             "bottleneck": r["bottleneck"],
             "useful_flops_frac": mf / hlo_total if hlo_total else 0.0,
-            "mfu_bound": (mf / rec["chips"] / t_bound) / PEAK_FLOPS
+            "mfu_bound": (mf / rec["chips"] / t_bound)
+            / peaks_for(r["device_kind"]).flops
             if t_bound else 0.0,
             "mem_gb_per_dev": r["peak_memory_gb"],
             "compile_s": rec["seconds_compile"],

@@ -53,7 +53,8 @@ def measure() -> list:
         rl = RL.from_costs(f"selection/two_round/{tag}", mesh.size, cost,
                            coll,
                            peak_memory_bytes=float(
-                               getattr(mem, "temp_size_in_bytes", 0)))
+                               getattr(mem, "temp_size_in_bytes", 0)),
+                           device_kind=RL.V5E)
         rec = {"arch": "selection-two-round", "shape": f"n{N}_k{K}_d{D}",
                "mesh": "pod16x16", "tag": tag, "precision": prec,
                "chips": mesh.size,

@@ -55,7 +55,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.core.precision import accum32
+from repro.core.precision import MXU, accum32
 
 Array = jax.Array
 
@@ -242,7 +242,8 @@ class FacilityLocation(SubmodularOracle):
         # matmul accepts storage-dtype (bf16) tiles but accumulates f32 —
         # the native MXU mixed-precision contract, a no-op for f32 input.
         sims = jnp.matmul(cand_feats, self.reference.T,
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=MXU)
         return jnp.maximum(sims, 0.0)
 
     def marginals(self, state, aux):
@@ -431,7 +432,8 @@ class GraphCut(SubmodularOracle):
 
             return ops.graph_cut_marginals(aux, self.total, state, self.lam)
         aux = accum32(aux)
-        lin = aux @ (self.total - 2.0 * self.lam * state)
+        lin = jnp.matmul(aux, self.total - 2.0 * self.lam * state,
+                         precision=MXU)
         return lin - self.lam * jnp.sum(aux * aux, axis=-1)
 
     def chunk_accept(self, state, cand_feats, eligible, tau, budget,
@@ -451,7 +453,8 @@ class GraphCut(SubmodularOracle):
         return state + aux_row
 
     def value(self, state):
-        return state @ self.total - self.lam * jnp.sum(state * state)
+        return jnp.dot(state, self.total, precision=MXU) \
+            - self.lam * jnp.sum(state * state)
 
 
 LOGDET_EPS = 1e-12  # Schur-complement clamp (exact math keeps it >= 1)
@@ -504,7 +507,7 @@ class LogDetDiversity(SubmodularOracle):
 
             return ops.logdet_marginals(aux, U, self.alpha)
         aux = accum32(aux)
-        proj = aux @ U.T
+        proj = jnp.matmul(aux, U.T, precision=MXU)
         resid = 1.0 + self.alpha * jnp.sum(aux * aux, axis=-1) \
             - (self.alpha ** 2) * jnp.sum(proj * proj, axis=-1)
         return jnp.log(jnp.maximum(resid, LOGDET_EPS))
@@ -529,11 +532,11 @@ class LogDetDiversity(SubmodularOracle):
     def add(self, state, aux_row):
         U, logdet, size = state
         aux_row = accum32(aux_row)
-        v = self.alpha * (U @ aux_row)
+        v = self.alpha * jnp.matmul(U, aux_row, precision=MXU)
         d2 = jnp.maximum(
             1.0 + self.alpha * jnp.sum(aux_row * aux_row) - jnp.sum(v * v),
             LOGDET_EPS)
-        u_new = (aux_row - v @ U) / jnp.sqrt(d2)
+        u_new = (aux_row - jnp.matmul(v, U, precision=MXU)) / jnp.sqrt(d2)
         return (U.at[size].set(u_new), logdet + jnp.log(d2), size + 1)
 
     def value(self, state):
@@ -586,7 +589,7 @@ class MutualInformationGaussian(SubmodularOracle):
 
             return ops.logdet_marginals(aux, U, self.alpha, scale=0.5)
         aux = accum32(aux)
-        proj = aux @ U.T
+        proj = jnp.matmul(aux, U.T, precision=MXU)
         resid = 1.0 + self.alpha * jnp.sum(aux * aux, axis=-1) \
             - (self.alpha ** 2) * jnp.sum(proj * proj, axis=-1)
         return 0.5 * jnp.log(jnp.maximum(resid, LOGDET_EPS))
@@ -608,11 +611,11 @@ class MutualInformationGaussian(SubmodularOracle):
     def add(self, state, aux_row):
         U, mi, size = state
         aux_row = accum32(aux_row)
-        v = self.alpha * (U @ aux_row)
+        v = self.alpha * jnp.matmul(U, aux_row, precision=MXU)
         d2 = jnp.maximum(
             1.0 + self.alpha * jnp.sum(aux_row * aux_row) - jnp.sum(v * v),
             LOGDET_EPS)
-        u_new = (aux_row - v @ U) / jnp.sqrt(d2)
+        u_new = (aux_row - jnp.matmul(v, U, precision=MXU)) / jnp.sqrt(d2)
         return (U.at[size].set(u_new), mi + 0.5 * jnp.log(d2), size + 1)
 
     def value(self, state):
@@ -652,7 +655,8 @@ class ExemplarClustering(SubmodularOracle):
         # bf16 tiles in, f32 accumulate (matmul via preferred_element_type,
         # the row norms on the accumulate plane)
         sims = jnp.matmul(cand_feats, self.reference.T,
-                          preferred_element_type=jnp.float32)
+                          preferred_element_type=jnp.float32,
+                          precision=MXU)
         sq = jnp.sum(jnp.square(accum32(cand_feats)), axis=-1, keepdims=True)
         return jnp.maximum(self._m0()[None, :] - 2.0 * sims + sq, 0.0)
 

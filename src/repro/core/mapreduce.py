@@ -619,11 +619,10 @@ def two_round_known_opt_mesh(oracle, cfg: MRConfig, mesh: Mesh,
         return _known_opt_select(oracle, rr, cfg, [opt / (2.0 * cfg.k)],
                                  [key])
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(data_spec, ids_spec, P(), P()),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(data_spec, ids_spec, P(), P()),
+                       out_specs=P(),
+                       check_vma=False)
 
     def run(feats_global, ids_global, opt, key):
         out = fn(feats_global, ids_global, jnp.asarray(opt, jnp.float32), key)
@@ -650,11 +649,10 @@ def multi_threshold_mesh(oracle, cfg: MRConfig, t: int, mesh: Mesh,
                                  grids.alg5_schedule(opt, cfg.k, t),
                                  rounds.chain_keys(key, t))
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(data_spec, ids_spec, P(), P()),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(data_spec, ids_spec, P(), P()),
+                       out_specs=P(),
+                       check_vma=False)
 
     def run(feats_global, ids_global, opt, key):
         out = fn(feats_global, ids_global, jnp.asarray(opt, jnp.float32), key)
@@ -686,11 +684,10 @@ def multi_epoch_mesh(oracle, cfg: MRConfig, mesh: Mesh, axes=("data",),
         return _epoch_select(oracle, rr, cfg, _epoch_keys_split(key, E), E,
                              kind)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(data_spec, ids_spec, P()),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(data_spec, ids_spec, P()),
+                       out_specs=P(),
+                       check_vma=False)
 
     def run(feats_global, ids_global, key):
         out = fn(feats_global, ids_global, key)
@@ -824,11 +821,10 @@ def two_round_batch_mesh(oracle, cfg: MRConfig, mesh: Mesh,
         return SelectionResult(sol_b, size_b, val_b, drops,
                                fb_d_q + fb_s_q)
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(data_spec, ids_spec, P(), P(), P(), P()),
-                   out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(data_spec, ids_spec, P(), P(), P(), P()),
+                       out_specs=P(),
+                       check_vma=False)
 
     def run(feats_global, ids_global, qb: QueryBatch, key):
         out = fn(feats_global, ids_global, qb.k, qb.graph_cut_lam,

@@ -30,8 +30,23 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+#: precision of every MXU matmul of the oracles and kernels.  The compute
+#: plane of the f32 policy is f32: on the TPU, XLA's DEFAULT precision
+#: would round f32 operands to bf16 before multiplying.  bf16 operands
+#: (the bf16 policy) multiply exactly either way, and on the CPU f32 is all
+#: there is, so HIGHEST changes no CPU result.
+MXU = jax.lax.Precision.HIGHEST
+
+
+def mxu_for(dtype):
+    """:data:`MXU` for f32 operands; None (the compiler's default) for
+    bf16 ones, which multiply exactly — and on which the TPU kernel
+    compiler refuses an f32 contract."""
+    return MXU if jnp.dtype(dtype) == jnp.float32 else None
 
 
 @dataclasses.dataclass(frozen=True)

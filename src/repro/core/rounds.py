@@ -254,14 +254,9 @@ def gather_packed(x, gather_axes, lead: int = 0):
     concatenating the per-machine buffers on the capacity axis.  ``lead``
     leading batch axes (e.g. a threshold-grid axis, or (query, grid) in
     the batched driver) are kept in place — the whole stack moves in one
-    collective."""
-    if lead == 0:
-        return jax.lax.all_gather(x, gather_axes, tiled=True)
-    g = jax.lax.all_gather(x, gather_axes)   # (m, *lead, cap, ...)
-    g = jnp.moveaxis(g, 0, lead)             # (*lead, m, cap, ...)
-    return g.reshape(g.shape[:lead]
-                     + (g.shape[lead] * g.shape[lead + 1],)
-                     + g.shape[lead + 2:])
+    collective, concatenated on the capacity axis itself, so no second,
+    transposed copy of the gathered stack is ever made."""
+    return jax.lax.all_gather(x, gather_axes, axis=lead, tiled=True)
 
 
 # ---------------------------------------------------------------------------

@@ -283,6 +283,11 @@ class DistributedSelector:
             self._batch_logs[Q] = self._batch_round_log(Q)
         self.round_log_batch = self._batch_logs[Q]
         res = self._batch_run(embeddings, ids, queries, key)
+        if self.spec.use_kernel and F.consumes_query_params(self.oracle):
+            # the per-query knob (graph_cut lam / log_det alpha) is traced
+            # on the query axis and the kernels bake it in at compile time,
+            # so this path ran the jnp oracle: say so, never silently
+            self.round_log_batch.note("kernel_bypassed", 1)
         self.round_log_batch.note("tau_fallback", jnp.sum(res.tau_fallback))
         self.round_log_batch.note("n_dropped", jnp.sum(res.n_dropped))
         self.round_log_batch.note("degraded_selects", res.degraded)

@@ -6,12 +6,12 @@ each row's marginal against the live oracle state held in VMEM scratch,
 accepting the row (state update in scratch, no HBM round-trip) whenever
 the gain clears tau and budget remains, and emitting
 
-    mask  (B,) int32  — 1 where the row was accepted, in stream order
-    state (1, dp) f32 — the post-sweep oracle state
-    gains (B,) f32    — each row's fresh marginal *at the moment it was
-                        scanned* (a valid stale upper bound forever, by
-                        submodularity — the engine feeds these straight
-                        into its stale-gains buffer)
+    mask  (1, B) int32 — 1 where the row was accepted, in stream order
+    state (1, dp) f32  — the post-sweep oracle state
+    gains (1, B) f32   — each row's fresh marginal *at the moment it was
+                         scanned* (a valid stale upper bound forever, by
+                         submodularity — the engine feeds these straight
+                         into its stale-gains buffer)
 
 This is exactly the paper's Algorithm-1 accept loop restricted to the
 tile, so the accepted sequence is bit-identical to what the dense engine
@@ -24,10 +24,14 @@ The sweep is shared; each oracle kernel supplies two callbacks working on
                         precomputed similarity row held in scratch)
     step_fn(st, row) -> (gain (), new_state (1, dp))
 
-Eligibility is consumed as a full (B,) vector and selected per row with a
-masked reduce (no dynamic scalar loads); tau/budget arrive as (1, 1)
-blocks (SMEM-shaped scalars).  Per-row outputs are kept in loop-carried
-vectors and written once at the end — no dynamic vector stores.
+Every per-row vector (eligibility, costs, mask, gains) is a lane-dense
+(1, B) row: eligibility is selected per row with a masked reduce (no
+dynamic scalar loads); tau/budget arrive as (1, 1) blocks (SMEM-shaped
+scalars).  Per-row outputs are kept in loop-carried rows and written once
+at the end — no dynamic vector stores.  Candidate rows are read with a
+dynamic sublane index, which the TPU compiler only accepts on an unpacked
+32-bit tile, so a bf16 tile is upcast once into f32 scratch
+(:func:`f32_rows`).
 """
 
 from __future__ import annotations
@@ -57,10 +61,10 @@ def run_sweep(nrows: int, elig_ref, tau_ref, budget_ref, mask_ref,
     B = nrows
     tau = tau_ref[0, 0]
     budget = budget_ref[0, 0]
-    elig = elig_ref[...]                                   # (B,) int32
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)[:, 0]
+    elig = elig_ref[...]                                   # (1, B) int32
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
     if cost_ref is not None:
-        cost = cost_ref[...]                               # (B,) f32
+        cost = cost_ref[...]                               # (1, B) f32
         cbud = cbud_ref[0, 0]
 
     def body(i, carry):
@@ -92,8 +96,8 @@ def run_sweep(nrows: int, elig_ref, tau_ref, budget_ref, mask_ref,
         return n_acc + acc.astype(jnp.int32), spent, mask, gains
 
     init = (jnp.zeros((), jnp.int32),
-            jnp.zeros((B,), jnp.int32),
-            jnp.zeros((B,), jnp.float32))
+            jnp.zeros((1, B), jnp.int32),
+            jnp.zeros((1, B), jnp.float32))
     if cost_ref is not None:
         init = (init[0], jnp.zeros((), jnp.float32), init[1], init[2])
     out = jax.lax.fori_loop(0, B, body, init)
@@ -101,6 +105,47 @@ def run_sweep(nrows: int, elig_ref, tau_ref, budget_ref, mask_ref,
     mask_ref[...] = mask
     gains_ref[...] = gains
     state_out_ref[...] = st_scratch[...]
+
+
+def row_operands(n: int, eligible, tau, budget, cost=None,
+                 cost_budget=None):
+    """The trailing per-row operands in :func:`row_specs` order; rows pad
+    with eligibility 0 (never accepted) and cost 0."""
+    ops = [_pad_axis(eligible.astype(jnp.int32), 0, n)[None, :],
+           jnp.asarray(tau, jnp.float32).reshape(1, 1),
+           jnp.asarray(budget, jnp.int32).reshape(1, 1)]
+    if cost is not None:
+        ops += [_pad_axis(cost.astype(jnp.float32), 0, n)[None, :],
+                jnp.asarray(cost_budget, jnp.float32).reshape(1, 1)]
+    return ops
+
+
+def row_specs(n: int, with_cost: bool):
+    """BlockSpecs of the trailing per-row operands: eligibility (1, n),
+    tau (1, 1), budget (1, 1), and for knapsack sweeps cost (1, n) plus
+    the remaining budget (1, 1)."""
+    vec = pl.BlockSpec((1, n), lambda i: (0, 0))
+    one = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    return [vec, one, one] + ([vec, one] if with_cost else [])
+
+
+def f32_rows(x_ref, upcast):
+    """The candidate tile as f32 rows that a dynamic row index may read:
+    ``x_ref`` itself when ``upcast`` — the scratch refs
+    :func:`upcast_scratch` asked for — is empty, else the (Bp, d) f32
+    scratch after one upcast copy of the tile."""
+    if not upcast:
+        return x_ref
+    (scratch,) = upcast
+    scratch[...] = x_ref[...].astype(jnp.float32)
+    return scratch
+
+
+def upcast_scratch(x):
+    """The f32 scratch :func:`f32_rows` needs for a non-f32 tile ``x``."""
+    if x.dtype == jnp.float32:
+        return []
+    return [pltpu.VMEM(x.shape, jnp.float32)]
 
 
 def accept_call(step_from, x, state, extras, eligible, tau, budget, *,
@@ -132,13 +177,8 @@ def accept_call(step_from, x, state, extras, eligible, tau, budget, *,
     state_p = _pad_axis(state.astype(jnp.float32), 0, dp)[None, :]
     extras_p = [_pad_axis(e.astype(jnp.float32), 0, dp)[None, :]
                 for e in extras]
-    elig_p = _pad_axis(eligible.astype(jnp.int32), 0, Bp)
-    tau_b = jnp.asarray(tau, jnp.float32).reshape(1, 1)
-    budget_b = jnp.asarray(budget, jnp.int32).reshape(1, 1)
-    cost_ops = []
-    if with_cost:
-        cost_ops = [_pad_axis(cost.astype(jnp.float32), 0, Bp),
-                    jnp.asarray(cost_budget, jnp.float32).reshape(1, 1)]
+    row_ops = row_operands(Bp, eligible, tau, budget, cost, cost_budget)
+    upcast = upcast_scratch(x_p)
 
     def kernel(*refs):
         x_ref, state_ref = refs[0], refs[1]
@@ -149,11 +189,12 @@ def accept_call(step_from, x, state, extras, eligible, tau, budget, *,
         if with_cost:
             cost_ref, cbud_ref = refs[base:base + 2]
             base += 2
-        mask_ref, state_out_ref, gains_ref, st_scratch = refs[base:]
+        mask_ref, state_out_ref, gains_ref, st_scratch = refs[base:base + 4]
         st_scratch[...] = state_ref[...]
+        rows = f32_rows(x_ref, refs[base + 4:])
 
         def row(i):
-            return x_ref[i, :].astype(jnp.float32)[None, :]
+            return rows[i, :][None, :]
 
         run_sweep(Bp, elig_ref, tau_ref, budget_ref, mask_ref,
                   state_out_ref, gains_ref, st_scratch, row,
@@ -167,25 +208,19 @@ def accept_call(step_from, x, state, extras, eligible, tau, budget, *,
             pl.BlockSpec((Bp, dp), lambda i: (0, 0)),
             pl.BlockSpec((1, dp), lambda i: (0, 0)),
             *[pl.BlockSpec((1, dp), lambda i: (0, 0))] * n_extras,
-            pl.BlockSpec((Bp,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            *([pl.BlockSpec((Bp,), lambda i: (0,)),
-               pl.BlockSpec((1, 1), lambda i: (0, 0))] if with_cost else []),
+            *row_specs(Bp, with_cost),
         ],
         out_specs=[
-            pl.BlockSpec((Bp,), lambda i: (0,)),
+            pl.BlockSpec((1, Bp), lambda i: (0, 0)),
             pl.BlockSpec((1, dp), lambda i: (0, 0)),
-            pl.BlockSpec((Bp,), lambda i: (0,)),
+            pl.BlockSpec((1, Bp), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
+            jax.ShapeDtypeStruct((1, Bp), jnp.int32),
             jax.ShapeDtypeStruct((1, dp), jnp.float32),
-            jax.ShapeDtypeStruct((Bp,), jnp.float32),
+            jax.ShapeDtypeStruct((1, Bp), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((1, dp), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((1, dp), jnp.float32), *upcast],
         interpret=interpret,
-    )(x_p, state_p, *extras_p, elig_p, tau_b, budget_b, *cost_ops)
-    return mask[:B] != 0, state_out[0, :d], gains[:B]
+    )(x_p, state_p, *extras_p, *row_ops)
+    return mask[0, :B] != 0, state_out[0, :d], gains[0, :B]

@@ -9,7 +9,12 @@ under a distance residual, zero feature columns under a linear term).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
 
 
 def ceil_to(x: int, m: int) -> int:
@@ -31,3 +36,34 @@ def pad_axis(x, axis: int, target: int, value=0.0):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths, constant_values=value)
+
+
+def row_block(C: int, block_c: int, dtype) -> tuple[int, int]:
+    """``(bc, Cp)``: the candidate tile height and the padded candidate
+    count for a kernel whose per-candidate results leave it as a
+    lane-dense ``(1, Cp)`` row (:func:`gains_out`).  Either one tile spans
+    the whole padded axis, or ``bc`` is a multiple of the 128-lane width —
+    the TPU compiler refuses any other partial block of that row."""
+    full = ceil_to(C, sublane(dtype))
+    if full <= block_c:
+        return full, full
+    bc = ceil_to(block_c, LANES)
+    return bc, ceil_to(C, bc)
+
+
+def gains_out(bc: int, Cp: int):
+    """``(out_specs, out_shape)`` for per-candidate f32 gains written as a
+    lane-dense ``(1, Cp)`` row, tile i owning columns ``[i*bc, (i+1)*bc)``
+    of the first grid axis.  A 1-D ``(Cp,)`` output in ``bc``-blocks does
+    not compile for the TPU: XLA tiles a 1-D f32 array by 1024, Mosaic by
+    the block."""
+    return (pl.BlockSpec((1, bc), lambda i, *_: (0, i)),
+            jax.ShapeDtypeStruct((1, Cp), jnp.float32))
+
+
+def mxu_params():
+    """Compiler params of the kernels with a (bc, d) x (d, br) MXU tile:
+    at d = 3,072 their double-buffered f32 tiles, and the bf16 halves an
+    f32-precision matmul splits them into, overflow the TPU compiler's
+    default 16 MiB of scoped VMEM; a v5e core has 128 MiB."""
+    return pltpu.CompilerParams(vmem_limit_bytes=64 * 2**20)

@@ -27,8 +27,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.precision import MXU
 from repro.kernels._tiling import ceil_to as _ceil_to
-from repro.kernels._tiling import sublane as _sublane
+from repro.kernels._tiling import gains_out as _gains_out
+from repro.kernels._tiling import mxu_params as _mxu_params
+from repro.kernels._tiling import row_block as _row_block
 from repro.kernels._tiling import pad_axis as _pad_axis
 
 DEFAULT_BC = 256   # candidate rows per tile
@@ -44,10 +47,12 @@ def _ex_kernel(cand_ref, refT_ref, refsq_ref, state_ref, out_ref):
 
     x = cand_ref[...].astype(jnp.float32)                # (bc, d)
     # MXU: (bc, d) @ (d, br) -> (bc, br) in f32
-    sims = jnp.dot(x, refT_ref[...], preferred_element_type=jnp.float32)
+    sims = jnp.dot(x, refT_ref[...], preferred_element_type=jnp.float32,
+                   precision=MXU)
     sq = jnp.sum(x * x, axis=-1, keepdims=True)          # (bc, 1)
     d2 = jnp.maximum(refsq_ref[...] - 2.0 * sims + sq, 0.0)
-    out_ref[...] += jnp.sum(jnp.maximum(state_ref[...] - d2, 0.0), axis=-1)
+    resid = jnp.maximum(state_ref[...] - d2, 0.0)
+    out_ref[...] += jnp.sum(resid, axis=-1)[None, :]
 
 
 @functools.partial(jax.jit,
@@ -57,9 +62,9 @@ def exemplar_marginals(cand, ref, state, *, block_c: int = DEFAULT_BC,
     """(C, d), (r, d), (r,) -> (C,) f32 exemplar-clustering marginal gains."""
     C, d = cand.shape
     r = ref.shape[0]
-    bc = min(block_c, _ceil_to(C, _sublane(cand.dtype)))
+    bc, Cp = _row_block(C, block_c, cand.dtype)
     br = min(block_r, _ceil_to(r, 128))
-    Cp, rp = _ceil_to(C, bc), _ceil_to(r, br)
+    rp = _ceil_to(r, br)
 
     cand_p = _pad_axis(cand, 0, Cp)
     ref32 = ref.astype(jnp.float32)
@@ -68,6 +73,7 @@ def exemplar_marginals(cand, ref, state, *, block_c: int = DEFAULT_BC,
     state_p = _pad_axis(state.astype(jnp.float32), 0, rp,
                         value=-jnp.inf)[None, :]                      # (1, rp)
 
+    out_spec, out_shape = _gains_out(bc, Cp)
     grid = (Cp // bc, rp // br)
     out = pl.pallas_call(
         _ex_kernel,
@@ -78,8 +84,9 @@ def exemplar_marginals(cand, ref, state, *, block_c: int = DEFAULT_BC,
             pl.BlockSpec((1, br), lambda i, j: (0, j)),
             pl.BlockSpec((1, br), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bc,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Cp,), jnp.float32),
+        out_specs=out_spec,
+        out_shape=out_shape,
+        compiler_params=_mxu_params(),
         interpret=interpret,
     )(cand_p, refT_p, refsq_p, state_p)
-    return out[:C]
+    return out[0, :C]

@@ -28,7 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._accept_common import run_sweep
+from repro.core.precision import mxu_for
+from repro.kernels._accept_common import row_operands, row_specs, run_sweep
 from repro.kernels._tiling import ceil_to as _ceil_to
 from repro.kernels._tiling import sublane as _sublane
 from repro.kernels._tiling import pad_axis as _pad_axis
@@ -44,7 +45,8 @@ def _fa_kernel(*refs, nrows, with_cost):
     mask_ref, state_out_ref, gains_ref, sims_scratch, st_scratch = refs[base:]
     # MXU: the (B, r) similarity block, rectified, lives only in scratch
     sims = jnp.dot(cand_ref[...], refT_ref[...],
-                   preferred_element_type=jnp.float32)
+                   preferred_element_type=jnp.float32,
+                   precision=mxu_for(cand_ref.dtype))
     sims_scratch[...] = jnp.maximum(sims, 0.0)
     st_scratch[...] = state_ref[...]
 
@@ -74,13 +76,7 @@ def facility_accept(cand, ref, state, eligible, tau, budget, *,
     refT_p = _pad_axis(ref.T, 1, rp)                        # (d, rp)
     state_p = _pad_axis(state.astype(jnp.float32), 0, rp,
                         value=jnp.inf)[None, :]             # (1, rp)
-    elig_p = _pad_axis(eligible.astype(jnp.int32), 0, Bp)
-    tau_b = jnp.asarray(tau, jnp.float32).reshape(1, 1)
-    budget_b = jnp.asarray(budget, jnp.int32).reshape(1, 1)
-    cost_ops = []
-    if with_cost:
-        cost_ops = [_pad_axis(cost.astype(jnp.float32), 0, Bp),
-                    jnp.asarray(cost_budget, jnp.float32).reshape(1, 1)]
+    row_ops = row_operands(Bp, eligible, tau, budget, cost, cost_budget)
 
     mask, state_out, gains = pl.pallas_call(
         functools.partial(_fa_kernel, nrows=Bp, with_cost=with_cost),
@@ -89,26 +85,22 @@ def facility_accept(cand, ref, state, eligible, tau, budget, *,
             pl.BlockSpec((Bp, d), lambda i: (0, 0)),
             pl.BlockSpec((d, rp), lambda i: (0, 0)),
             pl.BlockSpec((1, rp), lambda i: (0, 0)),
-            pl.BlockSpec((Bp,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            *([pl.BlockSpec((Bp,), lambda i: (0,)),
-               pl.BlockSpec((1, 1), lambda i: (0, 0))] if with_cost else []),
+            *row_specs(Bp, with_cost),
         ],
         out_specs=[
-            pl.BlockSpec((Bp,), lambda i: (0,)),
+            pl.BlockSpec((1, Bp), lambda i: (0, 0)),
             pl.BlockSpec((1, rp), lambda i: (0, 0)),
-            pl.BlockSpec((Bp,), lambda i: (0,)),
+            pl.BlockSpec((1, Bp), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
+            jax.ShapeDtypeStruct((1, Bp), jnp.int32),
             jax.ShapeDtypeStruct((1, rp), jnp.float32),
-            jax.ShapeDtypeStruct((Bp,), jnp.float32),
+            jax.ShapeDtypeStruct((1, Bp), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((Bp, rp), jnp.float32),
             pltpu.VMEM((1, rp), jnp.float32),
         ],
         interpret=interpret,
-    )(cand_p, refT_p, state_p, elig_p, tau_b, budget_b, *cost_ops)
-    return mask[:B] != 0, state_out[0, :r], gains[:B]
+    )(cand_p, refT_p, state_p, *row_ops)
+    return mask[0, :B] != 0, state_out[0, :r], gains[0, :B]

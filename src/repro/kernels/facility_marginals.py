@@ -30,8 +30,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.precision import mxu_for
 from repro.kernels._tiling import ceil_to as _ceil_to
-from repro.kernels._tiling import sublane as _sublane
+from repro.kernels._tiling import gains_out as _gains_out
+from repro.kernels._tiling import mxu_params as _mxu_params
+from repro.kernels._tiling import row_block as _row_block
 from repro.kernels._tiling import pad_axis as _pad_axis
 
 DEFAULT_BC = 256   # candidate rows per tile
@@ -48,10 +51,11 @@ def _fm_kernel(cand_ref, refT_ref, state_ref, out_ref):
 
     # MXU: (bc, d) @ (d, br) -> (bc, br) in f32
     sims = jnp.dot(cand_ref[...], refT_ref[...],
-                   preferred_element_type=jnp.float32)
+                   preferred_element_type=jnp.float32,
+                   precision=mxu_for(cand_ref.dtype))
     sims = jnp.maximum(sims, 0.0)                    # prep rectification
     resid = jnp.maximum(sims - state_ref[...], 0.0)  # marginal residual
-    out_ref[...] += jnp.sum(resid, axis=-1)
+    out_ref[...] += jnp.sum(resid, axis=-1)[None, :]
 
 
 @functools.partial(jax.jit,
@@ -65,15 +69,16 @@ def facility_marginals(cand, ref, state, *, block_c: int = DEFAULT_BC,
     """
     C, d = cand.shape
     r = ref.shape[0]
-    bc = min(block_c, _ceil_to(C, _sublane(cand.dtype)))
+    bc, Cp = _row_block(C, block_c, cand.dtype)
     br = min(block_r, _ceil_to(r, 128))
-    Cp, rp = _ceil_to(C, bc), _ceil_to(r, br)
+    rp = _ceil_to(r, br)
 
     cand_p = _pad_axis(cand, 0, Cp)
     refT_p = _pad_axis(ref.T, 1, rp)                       # (d, rp)
     state_p = _pad_axis(state.astype(jnp.float32), 0, rp,
                         value=jnp.inf)[None, :]            # (1, rp)
 
+    out_spec, out_shape = _gains_out(bc, Cp)
     grid = (Cp // bc, rp // br)
     out = pl.pallas_call(
         _fm_kernel,
@@ -83,11 +88,12 @@ def facility_marginals(cand, ref, state, *, block_c: int = DEFAULT_BC,
             pl.BlockSpec((d, br), lambda i, j: (0, j)),
             pl.BlockSpec((1, br), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bc,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Cp,), jnp.float32),
+        out_specs=out_spec,
+        out_shape=out_shape,
+        compiler_params=_mxu_params(),
         interpret=interpret,
     )(cand_p, refT_p, state_p)
-    return out[:C]
+    return out[0, :C]
 
 
 def _rrs_kernel(aux_ref, state_ref, out_ref):
@@ -99,7 +105,7 @@ def _rrs_kernel(aux_ref, state_ref, out_ref):
 
     resid = jnp.maximum(aux_ref[...].astype(jnp.float32) - state_ref[...],
                         0.0)
-    out_ref[...] += jnp.sum(resid, axis=-1)
+    out_ref[...] += jnp.sum(resid, axis=-1)[None, :]
 
 
 @functools.partial(jax.jit,
@@ -114,13 +120,14 @@ def rectified_residual_sum(aux, state, *, block_c: int = DEFAULT_BC,
     `aux - state` intermediate.
     """
     C, r = aux.shape
-    bc = min(block_c, _ceil_to(C, _sublane(aux.dtype)))
+    bc, Cp = _row_block(C, block_c, aux.dtype)
     br = min(block_r, _ceil_to(r, 128))
-    Cp, rp = _ceil_to(C, bc), _ceil_to(r, br)
+    rp = _ceil_to(r, br)
     aux_p = _pad_axis(_pad_axis(aux, 0, Cp), 1, rp)
     state_p = _pad_axis(state.astype(jnp.float32), 0, rp,
                         value=jnp.inf)[None, :]
 
+    out_spec, out_shape = _gains_out(bc, Cp)
     grid = (Cp // bc, rp // br)
     out = pl.pallas_call(
         _rrs_kernel,
@@ -129,8 +136,8 @@ def rectified_residual_sum(aux, state, *, block_c: int = DEFAULT_BC,
             pl.BlockSpec((bc, br), lambda i, j: (i, j)),
             pl.BlockSpec((1, br), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bc,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Cp,), jnp.float32),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
     )(aux_p, state_p)
-    return out[:C]
+    return out[0, :C]

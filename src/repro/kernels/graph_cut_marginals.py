@@ -12,7 +12,7 @@ bandwidth while keeping the broadcast `t - 2*lam*s` coefficient row and
 the x^2 intermediate in VMEM/VREGs — the XLA path materializes both as
 full (C, d) f32 buffers.
 
-Grid: (C/bc, d/bf); the f axis accumulates into the (bc,) output block
+Grid: (C/bc, d/bf); the f axis accumulates into the (1, bc) output row block
 (init at f-block 0).  Padding: x/total/state all pad with 0, so padded
 features contribute exactly 0 to the linear and quadratic terms.
 """
@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels._tiling import ceil_to as _ceil_to
-from repro.kernels._tiling import sublane as _sublane
+from repro.kernels._tiling import gains_out as _gains_out
+from repro.kernels._tiling import row_block as _row_block
 from repro.kernels._tiling import pad_axis as _pad_axis
 
 DEFAULT_BC = 256
@@ -42,7 +43,7 @@ def _gc_kernel(x_ref, total_ref, state_ref, out_ref, *, lam):
 
     x = x_ref[...].astype(jnp.float32)                    # (bc, bf)
     coef = total_ref[...] - 2.0 * lam * state_ref[...]    # (1, bf)
-    out_ref[...] += jnp.sum(x * coef - lam * x * x, axis=-1)
+    out_ref[...] += jnp.sum(x * coef - lam * x * x, axis=-1)[None, :]
 
 
 @functools.partial(jax.jit,
@@ -52,14 +53,15 @@ def graph_cut_marginals(x, total, state, lam: float = 0.5, *,
                         interpret: bool = False):
     """(C, d), (d,), (d,) -> (C,) f32 GraphCut marginal gains."""
     C, d = x.shape
-    bc = min(block_c, _ceil_to(C, _sublane(x.dtype)))
+    bc, Cp = _row_block(C, block_c, x.dtype)
     bf = min(block_f, _ceil_to(d, 128))
-    Cp, dp = _ceil_to(C, bc), _ceil_to(d, bf)
+    dp = _ceil_to(d, bf)
 
     x_p = _pad_axis(_pad_axis(x, 0, Cp), 1, dp)
     total_p = _pad_axis(total.astype(jnp.float32), 0, dp)[None, :]
     state_p = _pad_axis(state.astype(jnp.float32), 0, dp)[None, :]
 
+    out_spec, out_shape = _gains_out(bc, Cp)
     grid = (Cp // bc, dp // bf)
     out = pl.pallas_call(
         functools.partial(_gc_kernel, lam=lam),
@@ -69,8 +71,8 @@ def graph_cut_marginals(x, total, state, lam: float = 0.5, *,
             pl.BlockSpec((1, bf), lambda i, j: (0, j)),
             pl.BlockSpec((1, bf), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bc,), lambda i, j: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Cp,), jnp.float32),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
     )(x_p, total_p, state_p)
-    return out[:C]
+    return out[0, :C]

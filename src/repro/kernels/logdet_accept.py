@@ -41,6 +41,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.precision import MXU
+from repro.kernels._accept_common import (f32_rows, row_operands, row_specs,
+                                          upcast_scratch)
 from repro.kernels._tiling import ceil_to as _ceil_to
 from repro.kernels._tiling import sublane as _sublane
 from repro.kernels._tiling import pad_axis as _pad_axis
@@ -56,18 +59,19 @@ def _la_kernel(*refs, nrows, alpha, scale, eps, with_cost):
         cost_ref, cbud_ref = refs[base:base + 2]
         base += 2
     (mask_ref, u_out_ref, ld_out_ref, size_out_ref, gains_ref,
-     u_scratch) = refs[base:]
+     u_scratch) = refs[base:base + 6]
     B = nrows
     u_scratch[...] = u_ref[...]
+    rows = f32_rows(x_ref, refs[base + 6:])
     tau = tau_ref[0, 0]
     budget = budget_ref[0, 0]
     size0 = size_ref[0, 0]
-    elig = elig_ref[...]                                   # (B,) int32
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0)[:, 0]
+    elig = elig_ref[...]                                   # (1, B) int32
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
     kp = u_scratch.shape[0]
     k_iota = jax.lax.broadcasted_iota(jnp.int32, (kp, 1), 0)
     if with_cost:
-        cost = cost_ref[...]                               # (B,) f32
+        cost = cost_ref[...]                               # (1, B) f32
         cbud = cbud_ref[0, 0]
 
     def body(i, carry):
@@ -75,11 +79,12 @@ def _la_kernel(*refs, nrows, alpha, scale, eps, with_cost):
             n_acc, spent, ld, mask, gains = carry
         else:
             n_acc, ld, mask, gains = carry
-        x_i = x_ref[i, :].astype(jnp.float32)[None, :]     # (1, d)
+        x_i = rows[i, :][None, :]                          # (1, d)
         U = u_scratch[...]                                 # (kp, d)
         # MXU: border projection v = alpha * U x_i, contracted over d
         proj = jax.lax.dot_general(x_i, U, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
+                                   preferred_element_type=jnp.float32,
+                                   precision=MXU)
         v = alpha * proj                                   # (1, kp)
         sq = jnp.sum(x_i * x_i)
         d2 = jnp.maximum(1.0 + alpha * sq - jnp.sum(v * v), eps)
@@ -100,8 +105,8 @@ def _la_kernel(*refs, nrows, alpha, scale, eps, with_cost):
         def _accept():
             # rank-1 Gram–Schmidt append, written as a masked full-matrix
             # select onto the target row (no dynamic vector stores)
-            u_new = (x_i - jnp.dot(v, U, preferred_element_type=jnp.float32)
-                     ) / jnp.sqrt(d2)                      # (1, d)
+            u_new = (x_i - jnp.dot(v, U, preferred_element_type=jnp.float32,
+                                   precision=MXU)) / jnp.sqrt(d2)  # (1, d)
             u_scratch[...] = jnp.where(k_iota == size0 + n_acc, u_new, U)
 
         ld = ld + jnp.where(acc, gain, jnp.float32(0.0))
@@ -114,8 +119,8 @@ def _la_kernel(*refs, nrows, alpha, scale, eps, with_cost):
 
     init = (jnp.zeros((), jnp.int32),
             ld_ref[0, 0],
-            jnp.zeros((B,), jnp.int32),
-            jnp.zeros((B,), jnp.float32))
+            jnp.zeros((1, B), jnp.int32),
+            jnp.zeros((1, B), jnp.float32))
     if with_cost:
         init = (init[0], jnp.zeros((), jnp.float32)) + init[1:]
     out = jax.lax.fori_loop(0, B, body, init)
@@ -147,13 +152,7 @@ def logdet_accept(x, U, logdet, size, eligible, tau, budget,
     u_p = _pad_axis(U.astype(jnp.float32), 0, kp)          # (kp, d)
     ld_b = jnp.asarray(logdet, jnp.float32).reshape(1, 1)
     size_b = jnp.asarray(size, jnp.int32).reshape(1, 1)
-    elig_p = _pad_axis(eligible.astype(jnp.int32), 0, Bp)
-    tau_b = jnp.asarray(tau, jnp.float32).reshape(1, 1)
-    budget_b = jnp.asarray(budget, jnp.int32).reshape(1, 1)
-    cost_ops = []
-    if with_cost:
-        cost_ops = [_pad_axis(cost.astype(jnp.float32), 0, Bp),
-                    jnp.asarray(cost_budget, jnp.float32).reshape(1, 1)]
+    row_ops = row_operands(Bp, eligible, tau, budget, cost, cost_budget)
 
     mask, u_out, ld_out, size_out, gains = pl.pallas_call(
         functools.partial(_la_kernel, nrows=Bp, alpha=alpha, scale=scale,
@@ -164,30 +163,25 @@ def logdet_accept(x, U, logdet, size, eligible, tau, budget,
             pl.BlockSpec((kp, d), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((Bp,), lambda i: (0,)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            *([pl.BlockSpec((Bp,), lambda i: (0,)),
-               pl.BlockSpec((1, 1), lambda i: (0, 0))] if with_cost else []),
+            *row_specs(Bp, with_cost),
         ],
         out_specs=[
-            pl.BlockSpec((Bp,), lambda i: (0,)),
+            pl.BlockSpec((1, Bp), lambda i: (0, 0)),
             pl.BlockSpec((kp, d), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((Bp,), lambda i: (0,)),
+            pl.BlockSpec((1, Bp), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp,), jnp.int32),
+            jax.ShapeDtypeStruct((1, Bp), jnp.int32),
             jax.ShapeDtypeStruct((kp, d), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
-            jax.ShapeDtypeStruct((Bp,), jnp.float32),
+            jax.ShapeDtypeStruct((1, Bp), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((kp, d), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM((kp, d), jnp.float32),
+                        *upcast_scratch(x_p)],
         interpret=interpret,
-    )(x_p, u_p, ld_b, size_b, elig_p, tau_b, budget_b, *cost_ops)
-    return (mask[:B] != 0, u_out[:k], ld_out[0, 0], size_out[0, 0],
-            gains[:B])
+    )(x_p, u_p, ld_b, size_b, *row_ops)
+    return (mask[0, :B] != 0, u_out[:k], ld_out[0, 0], size_out[0, 0],
+            gains[0, :B])

@@ -26,8 +26,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.precision import MXU
 from repro.kernels._tiling import ceil_to as _ceil_to
-from repro.kernels._tiling import sublane as _sublane
+from repro.kernels._tiling import gains_out as _gains_out
+from repro.kernels._tiling import row_block as _row_block
 from repro.kernels._tiling import pad_axis as _pad_axis
 
 DEFAULT_BC = 256
@@ -37,13 +39,14 @@ RESID_EPS = 1e-12   # clamp for the Schur complement (exact math keeps it >= 1)
 def _ld_kernel(x_ref, ut_ref, out_ref, *, alpha, eps, scale):
     x = x_ref[...].astype(jnp.float32)                   # (bc, d)
     # MXU: (bc, d) @ (d, kp) projection onto the whitened selected basis
-    proj = jnp.dot(x, ut_ref[...], preferred_element_type=jnp.float32)
+    proj = jnp.dot(x, ut_ref[...], preferred_element_type=jnp.float32,
+                   precision=MXU)
     sq = jnp.sum(x * x, axis=-1)
     resid = 1.0 + alpha * sq - (alpha * alpha) * jnp.sum(proj * proj, axis=-1)
     gains = jnp.log(jnp.maximum(resid, eps))
     # scale=0.5 is the mutual-information oracle (0.5 * log det); the
     # python-level branch keeps the scale=1.0 lowering bit-identical
-    out_ref[...] = gains if scale == 1.0 else scale * gains
+    out_ref[...] = (gains if scale == 1.0 else scale * gains)[None, :]
 
 
 @functools.partial(jax.jit,
@@ -56,13 +59,13 @@ def logdet_marginals(x, U, alpha: float = 1.0, eps: float = RESID_EPS, *,
     (times the compile-time ``scale`` — 0.5 for the MI oracle)."""
     C, d = x.shape
     k = U.shape[0]
-    bc = min(block_c, _ceil_to(C, _sublane(x.dtype)))
-    Cp = _ceil_to(C, bc)
+    bc, Cp = _row_block(C, block_c, x.dtype)
     kp = _ceil_to(max(k, 1), 8)
 
     x_p = _pad_axis(x, 0, Cp)
     ut_p = _pad_axis(U.astype(jnp.float32).T, 1, kp)     # (d, kp)
 
+    out_spec, out_shape = _gains_out(bc, Cp)
     grid = (Cp // bc,)
     out = pl.pallas_call(
         functools.partial(_ld_kernel, alpha=alpha, eps=eps, scale=scale),
@@ -71,8 +74,8 @@ def logdet_marginals(x, U, alpha: float = 1.0, eps: float = RESID_EPS, *,
             pl.BlockSpec((bc, d), lambda i: (i, 0)),
             pl.BlockSpec((d, kp), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bc,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((Cp,), jnp.float32),
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
     )(x_p, ut_p)
-    return out[:C]
+    return out[0, :C]

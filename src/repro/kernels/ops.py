@@ -1,9 +1,19 @@
 """Public jit'd entry points for the Pallas kernels.
 
-Backend dispatch: on TPU the kernels compile natively; everywhere else
-(this container is CPU) they run under ``interpret=True``, which executes
-the kernel body in Python with identical semantics — that is how the
-shape/dtype sweep tests validate them against ref.py.
+Backend dispatch, decided by ``jax.default_backend()`` at trace time:
+
+* ``tpu`` — the kernels compile natively with Mosaic (a compiled step holds
+  one ``tpu_custom_call`` per kernel launch site);
+* ``cpu`` — the test backend (``JAX_PLATFORMS=cpu``): the kernels run under
+  ``interpret=True``, which executes the kernel body with identical
+  semantics — that is how the shape/dtype sweep tests validate them
+  against ref.py;
+* anything else raises: there is no lowering for it, and a silent
+  interpret fallback would hide that the device never ran a kernel.
+
+A program compiled ahead of time for a described TPU from a CPU host still
+sees the CPU backend here, so such a compile calls the kernel modules with
+``interpret=False`` directly (tests/test_tpu_compile.py).
 """
 
 from __future__ import annotations
@@ -27,7 +37,14 @@ from repro.kernels import weighted_coverage_marginals as _wc
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels have no lowering for backend {backend!r}: they "
+        "compile on 'tpu' and are interpreted on 'cpu' only")
 
 
 def facility_marginals(cand, ref, state, *, block_c=None, block_r=None):

@@ -101,7 +101,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         cost=cost,
         coll_by_type=coll,
         model_flops=RL.model_flops_for(cfg, shape),
-        peak_memory_bytes=_peak_bytes(mem))
+        peak_memory_bytes=_peak_bytes(mem),
+        device_kind=RL.V5E)
 
     rec = {
         "arch": arch, "shape": shape_name, "mesh": mesh_name,

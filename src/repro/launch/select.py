@@ -25,6 +25,7 @@ from repro.core.precision import PRECISION_NAMES
 from repro.core.selector import (ALGORITHMS, ORACLE_NAMES,
                                  DistributedSelector, SelectorSpec)
 from repro.core.threshold import ENGINES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_for
 
 
@@ -43,7 +44,7 @@ def main() -> None:
                     help="lazy/fused-engine chunk size")
     ap.add_argument("--use-kernel", action="store_true",
                     help="route oracle marginals/accepts through the "
-                         "Pallas kernels (interpret mode off-TPU)")
+                         "Pallas kernels (interpreted on the CPU backend)")
     ap.add_argument("--precision", default="f32",
                     choices=list(PRECISION_NAMES),
                     help="storage/compute precision policy (accumulators "
@@ -81,6 +82,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     mesh = make_mesh_for(len(jax.devices()), model_parallel=1)
     key = jax.random.PRNGKey(args.seed)
     kd, kr, ks = jax.random.split(key, 3)

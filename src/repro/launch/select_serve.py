@@ -62,6 +62,7 @@ import argparse
 import dataclasses
 import heapq
 import math
+import sys
 import time
 from typing import Optional
 
@@ -79,9 +80,14 @@ from repro.core.precision import PRECISION_NAMES
 from repro.core.selector import (DistributedSelector, OPT_FREE_ALGORITHMS,
                                  ORACLE_NAMES, SelectorSpec, make_oracle)
 from repro.core.threshold import ENGINES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_for
 from repro.streaming import SieveSpec, StreamingSelector
 from repro.streaming import persist
+
+#: errors a retry cannot cure: the same program fails the same way again
+_NOT_TRANSIENT = (jax.errors.JaxRuntimeError, jax.errors.JAXTypeError,
+                  jax.errors.JAXIndexError)
 
 
 class SelectionService:
@@ -214,12 +220,15 @@ class SelectionService:
         """Run ``fn`` with bounded retry + exponential backoff.  Each
         retried failure bumps ``<what>_retries``; exhaustion bumps
         ``<what>_failures`` and re-raises (the caller reports the reason —
-        a failure is never swallowed here)."""
+        a failure is never swallowed here).  JAX/XLA errors (a program the
+        compiler refuses, a device that ran out of memory, a tracing
+        error) are not transient: they fail at once, without a retry."""
         for attempt in range(self.retry_attempts):
             try:
                 return fn()
-            except Exception:       # noqa: BLE001
-                if attempt == self.retry_attempts - 1:
+            except Exception as e:      # noqa: BLE001
+                if attempt == self.retry_attempts - 1 or \
+                        isinstance(e, _NOT_TRANSIENT):
                     self.stats[f"{what}_failures"] = \
                         self.stats.get(f"{what}_failures", 0) + 1
                     raise
@@ -530,6 +539,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     mesh = make_mesh_for(len(jax.devices()), model_parallel=1)
     key = jax.random.PRNGKey(args.seed)
     kd, ki, ks = jax.random.split(key, 3)
@@ -580,6 +590,7 @@ def main() -> None:
     for req in synth_requests(args.requests, args.k, args.oracle, args.seed,
                               deadline_ms=args.deadline_ms):
         loop.submit(req)
+    failed = []        # ingests/checkpoints that exhausted their retries
     t_online = 0.0     # ingest/warm time, excluded from the serving qps
     t_serve = time.time()
     with mesh:
@@ -602,7 +613,9 @@ def main() -> None:
                 except Exception as e:      # noqa: BLE001
                     # retries exhausted: report the reason (shed-style,
                     # never silent) and keep serving the batch path — the
-                    # cursor-driven absorb will catch up next cadence step
+                    # cursor-driven absorb will catch up next cadence step;
+                    # the run still exits non-zero at the end
+                    failed.append(f"ingest @ step {loop.step}")
                     print(f"[select_serve] step {loop.step}: INGEST "
                           f"FAILED after {svc.retry_attempts} attempts "
                           f"({type(e).__name__}: {e}) — continuing; "
@@ -620,7 +633,9 @@ def main() -> None:
                 except RuntimeError as e:
                     # a PREVIOUS async save exhausted its retries; report
                     # it (never silent) and try again this step — the
-                    # final blocking save below re-raises if it persists
+                    # final blocking save below re-raises if it persists,
+                    # and the run exits non-zero at the end either way
+                    failed.append(f"checkpoint before step {loop.step}")
                     print(f"[select_serve] step {loop.step}: CHECKPOINT "
                           f"FAILED ({e}) — retrying this step")
                     svc.save(ckpt, loop.step, blocking=False)
@@ -664,6 +679,10 @@ def main() -> None:
         "reported shed"
     bad = [r for r in done if r["size"] > r["k"]]
     assert not bad, f"slots exceeded their budget: {bad}"
+    if failed:
+        print(f"[select_serve] FAILED for good: {', '.join(failed)}",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -1,8 +1,8 @@
 """Three-term roofline analysis from a compiled dry-run artifact.
 
-    compute    = HLO_FLOPs        / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes        / (chips * HBM_BW)
-    collective = collective_bytes / (chips * LINK_BW)
+    compute    = HLO_FLOPs        / (chips * Peaks.flops)
+    memory     = HLO_bytes        / (chips * Peaks.hbm_bw)
+    collective = collective_bytes / (chips * Peaks.link_bw)
 
 ``compiled.cost_analysis()`` supplies FLOPs and bytes accessed for the
 *partitioned per-device* module (GSPMD compiles one per-device program), so
@@ -12,7 +12,9 @@ post-partitioning HLO text and sum *operand* sizes of every collective op
 (operand size reconstructed from the result size and the op's semantics +
 replica group size).
 
-Hardware constants: TPU v5e (task-supplied).
+Hardware constants: per-chip peaks keyed by ``jax.Device.device_kind``
+(:data:`PEAKS`); a device kind missing from the table is an error, never a
+default.
 """
 
 from __future__ import annotations
@@ -21,9 +23,33 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # B/s per chip
-LINK_BW = 50e9             # B/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float      # bf16 FLOP/s per chip
+    hbm_bw: float     # HBM B/s per chip
+    link_bw: float    # B/s per ICI link
+
+
+#: ``device_kind`` of a TPU v5e chip, as JAX reports it
+V5E = "TPU v5 lite"
+
+#: Published per-chip peaks.  TPU v5e — Google Cloud documentation,
+#: "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s, and 1,600 Gbit/s
+#: of inter-chip interconnect per chip over its 4 ICI links (50 GB/s each).
+PEAKS: Dict[str, Peaks] = {
+    V5E: Peaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The published peaks of ``device_kind``; raises for a device the
+    table does not list."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -110,6 +136,7 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
 class Roofline:
     name: str
     chips: int
+    device_kind: str                  # key of PEAKS
     flops_per_device: float
     bytes_per_device: float
     coll_bytes_per_device: float
@@ -118,16 +145,20 @@ class Roofline:
     peak_memory_bytes: float = 0.0    # per device, from memory_analysis
 
     @property
+    def peaks(self) -> Peaks:
+        return peaks_for(self.device_kind)
+
+    @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes_per_device / LINK_BW
+        return self.coll_bytes_per_device / self.peaks.link_bw
 
     @property
     def bottleneck(self) -> str:
@@ -153,11 +184,13 @@ class Roofline:
         """Model-FLOPs utilization at the roofline bound."""
         if self.t_bound == 0:
             return 0.0
-        return (self.model_flops / self.chips / self.t_bound) / PEAK_FLOPS
+        return (self.model_flops / self.chips / self.t_bound) / \
+            self.peaks.flops
 
     def row(self) -> Dict:
         return {
             "name": self.name, "chips": self.chips,
+            "device_kind": self.device_kind,
             "t_compute_s": self.t_compute, "t_memory_s": self.t_memory,
             "t_collective_s": self.t_collective,
             "bottleneck": self.bottleneck,
@@ -172,17 +205,20 @@ class Roofline:
 
 def from_dryrun(name: str, chips: int, cost: Dict, hlo_text: str,
                 model_flops: float = 0.0,
-                peak_memory_bytes: float = 0.0) -> Roofline:
+                peak_memory_bytes: float = 0.0, *,
+                device_kind: str) -> Roofline:
     coll = collective_bytes(hlo_text)
     return from_costs(name, chips, cost, coll, model_flops,
-                      peak_memory_bytes)
+                      peak_memory_bytes, device_kind=device_kind)
 
 
 def from_costs(name: str, chips: int, cost: Dict, coll_by_type: Dict,
                model_flops: float = 0.0,
-               peak_memory_bytes: float = 0.0) -> Roofline:
+               peak_memory_bytes: float = 0.0, *,
+               device_kind: str) -> Roofline:
+    peaks_for(device_kind)          # an unknown device fails here, loudly
     return Roofline(
-        name=name, chips=chips,
+        name=name, chips=chips, device_kind=device_kind,
         flops_per_device=float(cost.get("flops", 0.0)),
         bytes_per_device=float(cost.get("bytes accessed", 0.0)),
         coll_bytes_per_device=float(sum(coll_by_type.values())),

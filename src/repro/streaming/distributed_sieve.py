@@ -171,9 +171,8 @@ def sieve_and_merge_mesh(oracle, spec: SieveSpec, mesh: Mesh,
                          b_sizes[best], jnp.maximum(b_vals[best], 0.0))
         return res._replace(n_dropped=jax.lax.psum(dropped, gather_axes))
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(body, mesh=mesh, in_specs=(data_spec, ids_spec),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(data_spec, ids_spec),
+                       out_specs=P(), check_vma=False)
 
     def run(feats_global, ids_global):
         res = SelectionResult(*fn(feats_global, ids_global))

@@ -253,6 +253,24 @@ def test_select_batch_mesh_matches_select():
     assert int(jnp.sum(resb.n_dropped)) == 0
 
 
+@pytest.mark.parametrize("oracle,bypassed", [("feature_coverage", False),
+                                              ("graph_cut", True)])
+def test_select_batch_records_kernel_bypass(oracle, bypassed):
+    """use_kernel=True with a per-query knob on the query axis (graph_cut
+    lam) runs the jnp oracle — the batch round log says so; an oracle with
+    no per-query knob keeps its kernels and records nothing."""
+    n, d, k = 128, 8, 4
+    rng = np.random.default_rng(21)
+    X = jnp.asarray((rng.random((n, d)).astype(np.float32)) ** 2)
+    mesh = make_mesh_for(len(jax.devices()), model_parallel=1)
+    spec = SelectorSpec(k=k, oracle=oracle, use_kernel=True)
+    sel = DistributedSelector(spec, mesh, n_total=n, feat_dim=d,
+                              total=X.sum(0))
+    sel.select_batch(X, make_query_batch([k, 2]), key=jax.random.PRNGKey(3))
+    assert ("kernel_bypassed" in sel.runtime_events()) == bypassed
+    assert ("kernel_bypassed" in sel.round_log_batch.summary()) == bypassed
+
+
 def test_batch_sim_and_mesh_round_logs_agree():
     """Sim and mesh batched drivers claim identical per-round bytes for the
     same machine count (the DESIGN.md §1 record-for-record invariant,

@@ -68,6 +68,20 @@ def test_block_shape_invariance(block_c, block_r):
     np.testing.assert_allclose(got, base, rtol=1e-5, atol=1e-4)
 
 
+def test_kernels_refuse_unknown_backend(monkeypatch):
+    """The interpret decision is explicit: compiled on tpu, interpreted on
+    cpu, and any other backend raises instead of interpreting in silence."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no lowering for backend 'gpu'"):
+        ops._interpret()
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret() is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._interpret() is False
+
+
 def test_ops_dispatch_cpu_interpret():
     """ops.* entry points run (interpret) on CPU and match ref."""
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(3), 3)

@@ -424,3 +424,47 @@ def test_service_ingest_retry_exhaustion_reports(monkeypatch):
     assert svc.stats["ingest_retries"] == 1
     assert svc.stats["ingest_failures"] == 1
     assert "ingest=1(+1 failed)" in svc.summary()
+
+
+def test_service_ingest_does_not_retry_jax_errors(monkeypatch):
+    """A JAX/XLA error (a refused compile, an exhausted device) fails the
+    same way on every attempt: it is counted as a failure at once, with no
+    retry and no backoff."""
+    n, d, k = 128, 8, 4
+    svc = SelectionService(SelectorSpec(k=k), _mesh(), _corpus(n, d, 24),
+                           stream_chunk=32, retry_attempts=3,
+                           retry_backoff_s=0.0)
+    svc._ensure_stream()
+
+    def refused(st, f, i, v):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+    monkeypatch.setattr(svc.stream, "_update", refused)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE"):
+        svc.ingest(_corpus(64, d, 25))
+    assert svc.stats["ingest_retries"] == 0
+    assert svc.stats["ingest_failures"] == 1
+
+
+def test_select_serve_exits_nonzero_when_ingest_fails_for_good(monkeypatch,
+                                                              capsys):
+    """The serve loop keeps serving past an ingest whose retries ran out,
+    then exits non-zero: a run that lost documents never reports success."""
+    from repro.launch import select_serve
+
+    def gone(self, docs):
+        raise RuntimeError("host corpus unreachable")
+
+    monkeypatch.setattr(select_serve.SelectionService, "ingest", gone)
+    # keep this process's compile cache setting as the session has it
+    monkeypatch.setattr(select_serve, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr("sys.argv", [
+        "select_serve", "--n", "128", "--k", "4", "--d", "8", "--slots", "2",
+        "--requests", "4", "--ingest-docs", "16", "--ingest-every", "1"])
+    with pytest.raises(SystemExit) as exit_info:
+        select_serve.main()
+    assert exit_info.value.code == 1
+    out = capsys.readouterr()
+    assert "INGEST FAILED" in out.out
+    assert "served=4" in out.out
+    assert "FAILED for good: ingest @ step 1" in out.err
