@@ -22,6 +22,11 @@ is 1 epoch vmapped over the unknown-OPT tau grid, and the paper's
 (1 - 1/e - eps) multi-epoch driver is E = ceil(1/eps) epochs over the
 same grid.
 
+Each primitive, here and in ``core/threshold.py``, traces under a
+``jax.named_scope`` named for its move (``sample``, ``tops``, ``filter``,
+``pack``, ``gather``, ``accept``), so every device op of every algorithm
+carries its move in its HLO ``op_name``.
+
 The paper's complexity measure is the number of synchronous communication
 rounds (and the per-machine message volume).  On a TPU pod a "round" is a
 collective phase; the drivers construct a RoundLog from their *static*
@@ -198,12 +203,14 @@ def epoch_round_log(cfg, m: int, feat_dim: int, epochs: int,
 # local round halves (what one machine computes before a gather)
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("sample")
 def local_sample(oracle, key, feats, ids, valid, p, cap):
     """Algorithm 3 local half: Bernoulli(p) sample, packed."""
     mask = (jax.random.uniform(key, ids.shape) < p) & valid
     return pack_by_mask(feats, ids, mask, cap)
 
 
+@jax.named_scope("filter")
 def local_filter(oracle, st, sol, feats, ids, valid, tau, cap, size=None,
                  k=None, chunk=None, constraint=None, cstate=None):
     """Algorithm 2 local half: survivors of ThresholdFilter, packed.
@@ -232,6 +239,7 @@ def local_filter(oracle, st, sol, feats, ids, valid, tau, cap, size=None,
     return pack_by_mask(feats, ids, mask, cap)
 
 
+@jax.named_scope("tops")
 def local_top(oracle, feats, ids, valid, cap, constraint=None):
     """Algorithm 7 local half: top-`cap` elements by singleton value
     (computed on the base features when ``feats`` carries a constraint
@@ -249,6 +257,7 @@ def local_top(oracle, feats, ids, valid, cap, constraint=None):
     return f, i, v, jnp.zeros((), jnp.int32)
 
 
+@jax.named_scope("gather")
 def gather_packed(x, gather_axes, lead: int = 0):
     """all_gather a packed message buffer inside a shard_map body,
     concatenating the per-machine buffers on the capacity axis.  ``lead``
@@ -416,6 +425,7 @@ def empty_solution(oracle, k, constraint=None):
             () if constraint is None else constraint.init_state())
 
 
+@jax.named_scope("accept")
 def greedy_step(oracle, carry, cands, tau, k, cfg, k_dyn=None,
                 constraint=None):
     """One central accept: extend the carried (state, sol, size, cstate)
@@ -439,6 +449,7 @@ def greedy_step(oracle, carry, cands, tau, k, cfg, k_dyn=None,
         constraint=constraint, cstate=cstate, cplane=plane)
 
 
+@jax.named_scope("accept")
 def grid_phase1(oracle, S, taus, k, cfg, k_dyn=None, constraint=None):
     """First central accept of a grid epoch: an independent empty-start
     greedy per threshold guess (the paper's parallel tau copies)."""
@@ -448,6 +459,7 @@ def grid_phase1(oracle, S, taus, k, cfg, k_dyn=None, constraint=None):
     return jax.vmap(p1)(taus)
 
 
+@jax.named_scope("accept")
 def sparse_sweep(oracle, L, schedule, cfg, k_dyn=None, constraint=None):
     """Algorithm 7's central half, generalized to a schedule: each guess
     lane runs its full descending threshold sequence over the gathered

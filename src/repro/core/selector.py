@@ -262,7 +262,8 @@ class DistributedSelector:
         assert self.spec.algorithm in ("two_round", "multi_epoch"), \
             "select_batch requires an OPT-free algorithm " \
             "(two_round or multi_epoch)"
-        k_max = int(jnp.max(queries.k))
+        with jax.profiler.TraceAnnotation("select.budget_check"):
+            k_max = int(jnp.max(queries.k))
         assert k_max <= self.spec.k, \
             (f"select_batch: per-query budget {k_max} exceeds the slot "
              f"buffer capacity spec.k={self.spec.k}; the engine would "

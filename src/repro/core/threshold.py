@@ -170,6 +170,7 @@ def _apply_accept(st, accept_now, new_state, cand_id, idx, k):
     return oracle_state, sol_ids, sol_size, taken
 
 
+@jax.named_scope("accept")
 def threshold_greedy(oracle, oracle_state, sol_ids, sol_size, cand_feats,
                      cand_ids, cand_valid, tau, k: int, accept: str = "first",
                      engine: str = "dense", chunk: int = DEFAULT_CHUNK,
@@ -220,6 +221,7 @@ def threshold_greedy(oracle, oracle_state, sol_ids, sol_size, cand_feats,
     return out
 
 
+@jax.named_scope("accept")
 def threshold_greedy_batch(oracle, oracle_states, sol_ids, sol_sizes,
                            cand_feats, cand_ids, cand_valid, taus, k: int,
                            k_dyn=None, bind=None, bind_params=None,
@@ -580,6 +582,7 @@ def _threshold_greedy_fused(oracle, oracle_state, sol_ids, sol_size,
             GreedyStats(out.n_evals, out.n_iters))
 
 
+@jax.named_scope("filter")
 def threshold_filter(oracle, oracle_state, cand_feats, cand_valid, tau,
                      chunk=None):
     """Algorithm 2: keep candidates whose marginal w.r.t. the current
@@ -613,6 +616,7 @@ def exclude_ids(cand_ids, cand_valid, sol_ids):
 
 
 @partial(jax.jit, static_argnums=(3,))
+@jax.named_scope("pack")
 def pack_by_mask(feats, ids, mask, cap: int, priority=None):
     """Compress masked rows into a fixed-capacity buffer.
 

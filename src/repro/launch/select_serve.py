@@ -198,15 +198,15 @@ class SelectionService:
         res = self.selector.select_batch(self.materialize(), queries, key)
         return res
 
-    def account(self, res, n_active: int):
-        """Fold one step's per-request outcomes into the service stats.
-        Slots are filled front-first, so only the first ``n_active`` lanes
-        are real requests — masked k=0 filler slots share the corpus-wide
-        degenerate flag and would inflate the event counts."""
-        self.stats["served"] += n_active
-        self.stats["tau_fallback_batch"] += int(jnp.sum(
-            res.tau_fallback[:n_active]))
-        self.stats["n_dropped"] += int(jnp.sum(res.n_dropped[:n_active]))
+    def account(self, rows):
+        """Fold one step's retired rows into the service stats, from the
+        ints each row already holds (``dropped``, ``tau_fallback``): one
+        row per real request, so masked k=0 filler slots, which share the
+        corpus-wide degenerate flag, never inflate the event counts."""
+        self.stats["served"] += len(rows)
+        self.stats["tau_fallback_batch"] += sum(r["tau_fallback"]
+                                                for r in rows)
+        self.stats["n_dropped"] += sum(r["dropped"] for r in rows)
 
     def account_shed(self, n_shed: int, n_miss: int = 0):
         """Deadline outcomes: ``n_shed`` requests refused at admission
@@ -390,62 +390,83 @@ class ServeLoop:
         self.queue.submit(req, now)
 
     def run_step(self) -> list:
-        """One serve step; returns the rows retired this step."""
-        svc, spec = self.svc, self.svc.spec
-        now = time.monotonic()
-        active, shed = self.queue.admit(self.slots, now, self.est_step_s)
-        for req in shed:
-            row = {"id": req.id, "k": req.k, "status": "shed",
-                   "latency_s": now - req.arrival_s,
-                   "reason": (f"deadline {req.deadline_ms:.0f}ms "
-                              f"unmeetable (est step "
-                              f"{(self.est_step_s or 0.0) * 1e3:.0f}ms)")}
-            self.shed.append(row)
-        svc.account_shed(len(shed))
-        if not active:
-            return []
+        """One serve step; returns the rows retired this step.
 
-        Q = self.slots
-        ks_q = [r.k for r in active] + [0] * (Q - len(active))
-        lam_q = [r.lam if r.lam is not None else spec.graph_cut_lam
-                 for r in active] + [spec.graph_cut_lam] * (Q - len(active))
-        alpha_q = [r.alpha if r.alpha is not None else spec.logdet_alpha
-                   for r in active] + [spec.logdet_alpha] * (Q - len(active))
-        qb = make_query_batch(ks_q, graph_cut_lam=lam_q,
-                              logdet_alpha=alpha_q)
+        A step is one ``serve.step`` profiler span (args: step, admitted,
+        shed, queued) over five children in order: serve.admit,
+        serve.batch, serve.dispatch, serve.wait, serve.retire.  A row's
+        latency and the step estimate end after the retire readbacks."""
+        with jax.profiler.TraceAnnotation("serve.step") as span:
+            svc, spec = self.svc, self.svc.spec
+            with jax.profiler.TraceAnnotation("serve.admit"):
+                now = time.monotonic()
+                active, shed = self.queue.admit(self.slots, now,
+                                                self.est_step_s)
+                for req in shed:
+                    row = {"id": req.id, "k": req.k, "status": "shed",
+                           "latency_s": now - req.arrival_s,
+                           "reason": (f"deadline {req.deadline_ms:.0f}ms "
+                                      f"unmeetable (est step "
+                                      f"{(self.est_step_s or 0.0) * 1e3:.0f}"
+                                      f"ms)")}
+                    self.shed.append(row)
+                svc.account_shed(len(shed))
+            span.set_metadata(step=self.step, admitted=len(active),
+                              shed=len(shed), queued=len(self.queue))
+            if not active:
+                return []
 
-        t0 = time.monotonic()
-        res = svc.select_batch(qb, key=jax.random.fold_in(self.key,
-                                                          self.step))
-        jax.block_until_ready(res.value)
-        finish = time.monotonic()
-        dt = finish - t0
-        if self.step == 0 and self.t_first is None:
-            # the compile-bearing step: report it, keep it out of the EWMA
-            self.t_first = dt
-            self.first_step_served = len(active)
-        elif self.est_step_s is None:
-            self.est_step_s = dt
-        else:
-            a = self.ewma_alpha
-            self.est_step_s = (1 - a) * self.est_step_s + a * dt
+            with jax.profiler.TraceAnnotation("serve.batch"):
+                Q = self.slots
+                ks_q = [r.k for r in active] + [0] * (Q - len(active))
+                lam_q = [r.lam if r.lam is not None else spec.graph_cut_lam
+                         for r in active] + \
+                    [spec.graph_cut_lam] * (Q - len(active))
+                alpha_q = [r.alpha if r.alpha is not None
+                           else spec.logdet_alpha for r in active] + \
+                    [spec.logdet_alpha] * (Q - len(active))
+                qb = make_query_batch(ks_q, graph_cut_lam=lam_q,
+                                      logdet_alpha=alpha_q)
 
-        rows, n_miss = [], 0
-        for slot, req in enumerate(active):
-            missed = finish > req.abs_deadline_s
-            n_miss += int(missed)
-            rows.append({"id": req.id, "k": req.k, "status": "ok",
-                         "size": int(res.sol_size[slot]),
-                         "value": float(res.value[slot]),
-                         "dropped": int(res.n_dropped[slot]),
-                         "tau_fallback": int(res.tau_fallback[slot]),
-                         "latency_s": finish - req.arrival_s,
-                         "deadline_miss": missed})
-        self.done.extend(rows)
-        svc.account(res, len(active))
-        svc.account_shed(0, n_miss)
-        self.step += 1
-        return rows
+            t0 = time.monotonic()
+            with jax.profiler.TraceAnnotation("serve.dispatch"):
+                res = svc.select_batch(qb, key=jax.random.fold_in(
+                    self.key, self.step))
+            with jax.profiler.TraceAnnotation("serve.wait"):
+                jax.block_until_ready(res.value)
+
+            with jax.profiler.TraceAnnotation("serve.retire"):
+                outs = [(int(res.sol_size[slot]), float(res.value[slot]),
+                         int(res.n_dropped[slot]),
+                         int(res.tau_fallback[slot]))
+                        for slot in range(len(active))]
+                finish = time.monotonic()
+                rows, n_miss = [], 0
+                for req, (size, value, dropped, fallback) in zip(active,
+                                                                  outs):
+                    missed = finish > req.abs_deadline_s
+                    n_miss += int(missed)
+                    rows.append({"id": req.id, "k": req.k, "status": "ok",
+                                 "size": size, "value": value,
+                                 "dropped": dropped, "tau_fallback": fallback,
+                                 "latency_s": finish - req.arrival_s,
+                                 "deadline_miss": missed})
+                self.done.extend(rows)
+                svc.account(rows)
+                svc.account_shed(0, n_miss)
+
+            dt = finish - t0
+            if self.step == 0 and self.t_first is None:
+                # the compile-bearing step: report it, keep it out of EWMA
+                self.t_first = dt
+                self.first_step_served = len(active)
+            elif self.est_step_s is None:
+                self.est_step_s = dt
+            else:
+                a = self.ewma_alpha
+                self.est_step_s = (1 - a) * self.est_step_s + a * dt
+            self.step += 1
+            return rows
 
 
 def synth_requests(n_requests: int, k_max: int, oracle: str, seed: int,

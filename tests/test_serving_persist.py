@@ -268,7 +268,9 @@ def test_service_stats_split_batch_vs_warm():
                            stream_chunk=64)
     res = svc.select_batch(make_query_batch([k, k // 2]),
                            key=jax.random.PRNGKey(0))
-    svc.account(res, 2)
+    svc.account([{"dropped": int(res.n_dropped[q]),
+                  "tau_fallback": int(res.tau_fallback[q])}
+                 for q in range(2)])
     svc.select_warm()
     assert set(svc.stats) >= {"tau_fallback_batch", "tau_fallback_warm",
                               "shed", "deadline_miss"}

@@ -366,7 +366,9 @@ def test_select_serve_service_ingest_warm():
 
     qb = make_query_batch([k, k // 2])
     res = svc.select_batch(qb, key=jax.random.PRNGKey(0))
-    svc.account(res, 2)
+    svc.account([{"dropped": int(res.n_dropped[q]),
+                  "tau_fallback": int(res.tau_fallback[q])}
+                 for q in range(2)])
     assert svc.stats["served"] == 2
 
     info = svc.ingest((rng.random((64, d)).astype(np.float32)) ** 2)
