@@ -299,7 +299,9 @@ class DistributedSelector:
         degraded_selects, ...) summed across every select()/select_batch()
         this selector served — the single-query round log plus every
         slot-width batch log — merged with the fault-injection records
-        (``fault_*`` keys, from RoundLog.fault_events()).  This is the one
+        (``fault_*`` keys, from RoundLog.fault_events()) and with the
+        coverage filter's route counts of the process (``marginals_*``,
+        kernels.coverage_marginals.lane_stats).  This is the one
         place the lazy device scalars are forced to ints, so serving
         stats/SLO dashboards read one dict instead of reaching into per-Q
         RoundLogs."""
@@ -319,6 +321,14 @@ class DistributedSelector:
                     out[key] = min(out.get(key, v), v)
                 else:
                     out[key] = out.get(key, 0) + v
+        # the coverage filter's route in each program built so far in this
+        # process (trace-time counts, not per select)
+        from repro.kernels.coverage_marginals import lane_stats
+        lanes = lane_stats()
+        if lanes["fused_calls"] or lanes["per_lane_calls"]:
+            out["marginals_fused_calls"] = lanes["fused_calls"]
+            out["marginals_fused_lanes"] = lanes["fused_lanes"]
+            out["marginals_per_lane_calls"] = lanes["per_lane_calls"]
         return out
 
     def opt_upper_bound(self, embeddings) -> jax.Array:

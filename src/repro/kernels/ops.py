@@ -70,14 +70,11 @@ def rectified_residual_sum(aux, state, *, block_c=None, block_r=None):
 
 
 def coverage_marginals(x, state, weights=None, *, block_c=None, block_f=None):
-    """Fused (C,d),(d,)->(C,) FeatureCoverage marginals."""
-    kw = {}
-    if block_c:
-        kw["block_c"] = block_c
-    if block_f:
-        kw["block_f"] = block_f
-    return _cm.coverage_marginals(x, state, weights,
-                                  interpret=_interpret(), **kw)
+    """Fused (C,d),(d,)->(C,) FeatureCoverage marginals.  Under a vmap over
+    ``state`` alone the block is read once for every lane
+    (``coverage_marginals.routed``)."""
+    return _cm.routed(block_c or _cm.DEFAULT_BC, block_f or _cm.DEFAULT_BF,
+                      _interpret())(x, state, weights)
 
 
 def saturated_coverage_marginals(x, state, cap, weights=None, *,

@@ -203,6 +203,178 @@ def test_feature_coverage_oracle_kernel_route():
 
 
 # ---------------------------------------------------------------------------
+# many-lane coverage marginals: one pass over x for a stack of states
+# ---------------------------------------------------------------------------
+
+from repro.kernels import coverage_marginals as cm  # noqa: E402
+
+
+def _lanes_case(C, d, L, dtype, weighted):
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(C * 7 + d + L), 3)
+    x = jnp.abs(_rand(k1, (C, d), dtype))
+    states = jnp.abs(_rand(k2, (L, d), jnp.float32)) * 3.0
+    w = jnp.abs(_rand(k3, (d,), jnp.float32)) if weighted else None
+    return x, states, w
+
+
+def _one_lane_stack(x, states, w):
+    """The one-lane kernel called once per state (a lax.map, no vmap)."""
+    return jax.lax.map(
+        lambda s: coverage_marginals(x, s, w, interpret=True), states)
+
+
+@pytest.mark.parametrize("C,d", SHAPES_CM)
+@pytest.mark.parametrize("L", [1, 5, 37, 296])
+def test_coverage_marginals_lanes_bit_exact(C, d, L):
+    """Each lane of the many-lane kernel equals the one-lane kernel bit for
+    bit, for f32 and bf16 x, weighted and not."""
+    for dtype in DTYPES:
+        for weighted in (False, True):
+            x, states, w = _lanes_case(C, d, L, dtype, weighted)
+            got = cm.coverage_marginals_lanes(x, states, w, interpret=True)
+            assert got.shape == (L, C)
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(_one_lane_stack(
+                                              x, states, w)))
+
+
+def _stats_delta(fn):
+    before = cm.lane_stats()
+    out = fn()
+    after = cm.lane_stats()
+    return out, {k: after[k] - before[k] for k in after}
+
+
+@pytest.mark.parametrize("batched", ["state", "x", "weights"])
+def test_coverage_marginals_vmap_routing(batched):
+    """A vmap over the state alone takes the many-lane kernel; one that
+    batches x or the weights keeps Pallas's per-lane batching.  Both give
+    the one-lane kernel's numbers."""
+    L, C, d = 6, 100, 96
+    x, states, w = _lanes_case(C, d, L, jnp.float32, True)
+    xs = jnp.stack([x * (1 + i) for i in range(L)])
+    ws = jnp.stack([w * (1 + i) for i in range(L)])
+    fn, args, want = {
+        "state": (lambda s: ops.coverage_marginals(x, s, w), (states,),
+                  _one_lane_stack(x, states, w)),
+        "x": (lambda xx, s: ops.coverage_marginals(xx, s, w), (xs, states),
+              jnp.stack([coverage_marginals(xs[i], states[i], w,
+                                            interpret=True)
+                         for i in range(L)])),
+        "weights": (lambda s, ww: ops.coverage_marginals(x, s, ww),
+                    (states, ws),
+                    jnp.stack([coverage_marginals(x, states[i], ws[i],
+                                                  interpret=True)
+                               for i in range(L)])),
+    }[batched]
+    got, delta = _stats_delta(lambda: jax.jit(jax.vmap(fn))(*args))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if batched == "state":
+        assert delta == {"fused_calls": 1, "fused_lanes": L,
+                         "per_lane_calls": 0}
+    else:
+        assert delta == {"fused_calls": 0, "fused_lanes": 0,
+                         "per_lane_calls": 1}
+
+
+@pytest.mark.parametrize("outer,inner,x_outer", [
+    (3, 4, False),      # queries x lanes: both fold into 12 lanes
+    (2, 5, True),       # machines x lanes: lanes fused, machines a grid axis
+    (1, 5, True),       # one machine: an axis of size 1 is no axis
+])
+def test_coverage_marginals_nested_vmaps(outer, inner, x_outer):
+    """Nested vmaps match the un-kerneled FeatureCoverage, and the
+    state-only axes fold into one many-lane call."""
+    from repro.core import FeatureCoverage
+    C, d = 130, 40
+    kx, ks = jax.random.split(jax.random.PRNGKey(outer * 10 + inner))
+    xm = jnp.abs(_rand(kx, (outer, C, d), jnp.float32))
+    S = jnp.abs(_rand(ks, (outer, inner, d), jnp.float32))
+    plain = FeatureCoverage(feat_dim=d)
+    fused = FeatureCoverage(feat_dim=d, use_kernel=True)
+
+    def run(orc):
+        lanes = lambda x, s: jax.vmap(lambda st: orc.marginals(st, x))(s)
+        if x_outer:                                  # (machines, lanes)
+            return jax.jit(jax.vmap(lanes))(xm, S)
+        return jax.jit(jax.vmap(lambda s: lanes(xm[0], s)))(S)
+
+    got, delta = _stats_delta(lambda: run(fused))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(run(plain)),
+                               rtol=1e-5, atol=1e-5)
+    lanes = inner if x_outer else outer * inner
+    assert delta == {"fused_calls": 1, "fused_lanes": lanes,
+                     "per_lane_calls": 0}
+
+
+def _grouped_corpus(seed, n=512, d=48, groups=8):
+    """Contiguous row groups, each bright on its own band of features."""
+    rng = np.random.default_rng(seed)
+    band = d // groups
+    X = rng.uniform(0.0, 0.05, (n, d)).astype(np.float32)
+    for g in range(groups):
+        rows = slice(g * n // groups, (g + 1) * n // groups)
+        X[rows, g * band:(g + 1) * band] += rng.uniform(
+            0.2, 0.66) * rng.uniform(0.75, 1.25, (n // groups, band))
+    return jnp.asarray(X)
+
+
+def _per_lane_route(x, state, weights=None, **_):
+    """The one-lane kernel with no vmap rule: Pallas batches every lane."""
+    return coverage_marginals(x, state, weights, interpret=True)
+
+
+@pytest.mark.parametrize("driver", ["two_round", "two_round_batch",
+                                    "selector", "selector_batch"])
+def test_coverage_lane_fusion_end_to_end(driver, monkeypatch):
+    """Selections with use_kernel=True return the same ids and values with
+    the many-lane filter as with every lane read apart."""
+    from repro.core import (DistributedSelector, FeatureCoverage, MRConfig,
+                            SelectorSpec, make_query_batch, two_round_sim,
+                            two_round_batch_sim)
+    from repro.launch.mesh import make_mesh_for
+    n, d, k, m = 512, 48, 8, 2
+    X = _grouped_corpus(3, n, d)
+    key = jax.random.PRNGKey(5)
+    qb = make_query_batch([k, k // 2])
+    oracle = FeatureCoverage(feat_dim=d, use_kernel=True)
+    cfg = MRConfig(k=k, n_total=n, n_machines=m, engine="fused")
+    shards = (X.reshape(m, n // m, d),
+              jnp.arange(n, dtype=jnp.int32).reshape(m, n // m),
+              jnp.ones((m, n // m), bool))
+
+    def select():
+        if driver == "two_round":
+            return jax.jit(lambda *a: two_round_sim(
+                oracle, *a, cfg, key)[0])(*shards)
+        if driver == "two_round_batch":
+            return jax.jit(lambda *a: two_round_batch_sim(
+                oracle, *a, qb, cfg, key)[0])(*shards)
+        mesh = make_mesh_for(len(jax.devices()), model_parallel=1)
+        spec = SelectorSpec(k=k, oracle="feature_coverage", engine="fused",
+                            use_kernel=True)
+        sel = DistributedSelector(spec, mesh, n_total=n, feat_dim=d)
+        sels.append(sel)
+        if driver == "selector":
+            return sel.select(X, key=key)
+        return sel.select_batch(X, qb, key=key)
+
+    sels = []
+    fused, delta = _stats_delta(select)
+    assert delta["fused_calls"] >= 1
+    if sels:
+        ev = sels[0].runtime_events()
+        assert ev["marginals_fused_lanes"] >= delta["fused_lanes"] > 0
+    monkeypatch.setattr(ops, "coverage_marginals", _per_lane_route)
+    apart, delta = _stats_delta(select)
+    assert delta["fused_calls"] == 0
+    np.testing.assert_array_equal(np.asarray(fused.sol_ids),
+                                  np.asarray(apart.sol_ids))
+    np.testing.assert_array_equal(np.asarray(fused.value),
+                                  np.asarray(apart.value))
+
+
+# ---------------------------------------------------------------------------
 # saturated_coverage_marginals kernel
 # ---------------------------------------------------------------------------
 

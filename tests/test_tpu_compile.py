@@ -37,6 +37,7 @@ C = 4_096      # candidates per marginals call (a filter tile)
 B = 128        # rows per accept sweep (the fused engine's default chunk)
 R = 1_024      # reference rows (facility / exemplar)
 KB = 64        # log-det basis rows (k)
+J = 37         # threshold lanes at eps = 0.15
 
 
 @pytest.fixture(scope="module")
@@ -81,6 +82,18 @@ I32 = jnp.int32
 MARGINALS = {
     "coverage": (lambda x, s: cm.coverage_marginals(x, s),
                  [("X", (C, D)), (F32, (D,))]),
+    # the threshold lanes of one selection, and of an 8-slot served batch
+    "coverage_lanes37": (lambda x, s: cm.coverage_marginals_lanes(x, s),
+                         [("X", (C, D)), (F32, (J, D))]),
+    "coverage_lanes296": (lambda x, s: cm.coverage_marginals_lanes(x, s),
+                          [("X", (C, D)), (F32, (8 * J, D))]),
+    "coverage_lanes37_weighted": (
+        lambda x, s, w: cm.coverage_marginals_lanes(x, s, w),
+        [("X", (C, D)), (F32, (J, D)), (F32, (D,))]),
+    "coverage_vmapped_lanes": (
+        lambda x, s: jax.vmap(lambda st: cm.routed(
+            cm.DEFAULT_BC, cm.DEFAULT_BF, False)(x, st, None))(s),
+        [("X", (C, D)), (F32, (J, D))]),
     "saturated_coverage": (
         lambda x, s, c: sm.saturated_coverage_marginals(x, s, c),
         [("X", (C, D)), (F32, (D,)), (F32, (D,))]),
