@@ -36,7 +36,8 @@ from typing import Dict, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.rounds import FaultRecord, RoundLog
+from repro.core.rounds import (FaultRecord, RoundLog,
+                               filter_accept_gathered)
 
 #: fault kinds a FaultPlan can realize, in record order
 FAULT_KINDS = ("shard_loss", "msg_drop", "msg_corrupt", "straggler")
@@ -137,7 +138,8 @@ class FaultyRounds:
     """Fault-injecting wrapper over a SimRounds/MeshRounds substrate.
 
     Conforms to the same five-op contract (sample / tops / filter /
-    filter_grid / finalize_drops, plus the ``begin_epoch`` boundary hook),
+    filter_grid / finalize_drops, plus the ``begin_epoch`` boundary hook
+    and ``filter_accept_grid``, here always one block),
     so every epoch-engine driver runs over it unmodified.  Faults are
     realized HOST-SIDE from the plan's stateless draws at trace time: both
     backends issue the same op sequence in the same order, so the realized
@@ -271,6 +273,11 @@ class FaultyRounds:
                     k, chunk):
         return self.degrade(*self.inner.filter_grid(
             oracle, st_j, sol_j, size_j, cstate_j, taus, cap, k, chunk))
+
+    def filter_accept_grid(self, oracle, carry, taus, cap, k, chunk, accept):
+        # one block: the faults land on the whole gathered stack at once
+        return filter_accept_gathered(self, oracle, carry, taus, cap, k,
+                                      chunk, accept)
 
     def finalize_drops(self, drops):
         return self.inner.finalize_drops(drops)

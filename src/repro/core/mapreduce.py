@@ -559,7 +559,8 @@ def _query_grid_a(orc, cfg, S, K, kq, v_dense=None):
 
 def _query_grid_b(orc, cfg, K, kq, taus, carry, R, L, v_sparse=None):
     """One query's phase 2 + sparse path + best-of: complete every grid
-    lane on its gathered survivors, sweep the sparse grid over the
+    lane on its gathered survivors (``R``; None where the lanes were
+    completed already, in lane blocks), sweep the sparse grid over the
     top-singleton pool, keep the best lane."""
     st_j, sol_j, size_j = carry[:3]
 
@@ -569,7 +570,10 @@ def _query_grid_b(orc, cfg, K, kq, taus, carry, R, L, v_sparse=None):
                                               k_dyn=kq)
         return sol, size, orc.value(st)
 
-    dsol, dsize, dval = jax.vmap(p2)(st_j, sol_j, size_j, *R, taus)
+    if R is None:
+        dsol, dsize, dval = sol_j, size_j, jax.vmap(orc.value)(st_j)
+    else:
+        dsol, dsize, dval = jax.vmap(p2)(st_j, sol_j, size_j, *R, taus)
     if v_sparse is not None:
         taus_s, fb_s = _tau_grid_from_v(cfg, v_sparse, kq)
     else:
@@ -663,7 +667,8 @@ def multi_threshold_mesh(oracle, cfg: MRConfig, t: int, mesh: Mesh,
 
 def multi_epoch_mesh(oracle, cfg: MRConfig, mesh: Mesh, axes=("data",),
                      data_spec=None, epochs: Optional[int] = None,
-                     schedule_kind: Optional[str] = None):
+                     schedule_kind: Optional[str] = None,
+                     _block_lanes=None):
     """The multi-epoch (1 - 1/e - eps) driver on a device mesh: E epochs
     of the unknown-OPT grid engine (2E all_gather phases), sparse path
     riding the same rounds.  ``epochs=1`` reproduces two_round_mesh
@@ -679,7 +684,7 @@ def multi_epoch_mesh(oracle, cfg: MRConfig, mesh: Mesh, axes=("data",),
     def body(feats, ids, key):
         rr = MeshRounds(oracle, feats, ids, ids >= 0, gather_axes,
                         precision=cfg.precision_policy,
-                        constraint=cfg.constraint)
+                        constraint=cfg.constraint, _block_lanes=_block_lanes)
         rr = faults_mod.with_faults(rr, cfg.faults, log, m, cfg.n_total)
         return _epoch_select(oracle, rr, cfg, _epoch_keys_split(key, E), E,
                              kind)
@@ -697,7 +702,7 @@ def multi_epoch_mesh(oracle, cfg: MRConfig, mesh: Mesh, axes=("data",),
 
 
 def two_round_mesh(oracle, cfg: MRConfig, mesh: Mesh,
-                   axes=("data",), data_spec=None):
+                   axes=("data",), data_spec=None, _block_lanes=None):
     """Theorem 8 on a device mesh: the dense grid (Alg. 6) and sparse
     top-singletons path (Alg. 7) share the same two all_gather rounds; the
     best solution over all thresholds/paths wins.  OPT is NOT an input —
@@ -705,11 +710,11 @@ def two_round_mesh(oracle, cfg: MRConfig, mesh: Mesh,
     the production default of DistributedSelector, and exactly the 1-epoch
     instantiation of multi_epoch_mesh."""
     return multi_epoch_mesh(oracle, cfg, mesh, axes, data_spec=data_spec,
-                            epochs=1)
+                            epochs=1, _block_lanes=_block_lanes)
 
 
 def two_round_batch_mesh(oracle, cfg: MRConfig, mesh: Mesh,
-                         axes=("data",), data_spec=None):
+                         axes=("data",), data_spec=None, _block_lanes=None):
     """Theorem 8 for Q queries on a device mesh — the query axis on the
     production substrate.
 
@@ -717,7 +722,9 @@ def two_round_batch_mesh(oracle, cfg: MRConfig, mesh: Mesh,
     carries every query: round 1 gathers the SHARED Bernoulli sample (drawn
     once, query-oblivious) plus the per-query top-singleton buffers stacked
     on a leading (Q,) axis; round 2 gathers the (Q, J, cap) survivor
-    buffers in one collective.  The central phases vmap over queries with
+    buffers, in blocks of ceil(Q*J/m) lanes one after another
+    (``rounds.gather_accept``; one block on one machine, or under a fault
+    plan).  The central phases vmap over queries with
     per-query dynamic budgets and bind_query'd oracle hyper-parameters.
     Amortization: Q concurrent selection requests cost ONE partition, ONE
     sample round, ONE gather round — not Q compiled calls serialized on
@@ -752,7 +759,8 @@ def two_round_batch_mesh(oracle, cfg: MRConfig, mesh: Mesh,
         # the round backend gathers (identity under the default policy)
         feats = cfg.precision_policy.cast_storage(feats)
         rr = MeshRounds(oracle, feats, ids, valid, gather_axes,
-                        precision=cfg.precision_policy)
+                        precision=cfg.precision_policy,
+                        _block_lanes=_block_lanes)
         rr = faults_mod.with_faults(rr, cfg.faults, fault_log, m,
                                     cfg.n_total)
 
@@ -773,6 +781,7 @@ def two_round_batch_mesh(oracle, cfg: MRConfig, mesh: Mesh,
                 lambda lam, alpha: rounds.local_top(
                     bind_query(oracle, lam, alpha), feats, ids, valid, t_cap)
             )(qlam, qalpha)
+            rounds.count_gathered((tf, ti, tv), gather_axes)
             Ltf = rounds.gather_packed(tf, gather_axes, lead=1)  # (Q, m*t_cap, d)
             Lti = rounds.gather_packed(ti, gather_axes, lead=1)
             Ltv = rounds.gather_packed(tv, gather_axes, lead=1)
@@ -796,19 +805,43 @@ def two_round_batch_mesh(oracle, cfg: MRConfig, mesh: Mesh,
         (taus_q, fb_d_q, st_q, sol_q, size_q, rf, ri, rv,
          rdrop_q) = jax.vmap(phase_a)(qk, qlam, qalpha)
 
-        # ---- round 2: ONE gather of the (Q, J, cap) survivor stack ------
-        Rf = rounds.gather_packed(rf, gather_axes, lead=2)  # (Q, J, m*f_cap, d)
-        Ri = rounds.gather_packed(ri, gather_axes, lead=2)
-        Rv = rounds.gather_packed(rv, gather_axes, lead=2)
-        (Rf, Ri, Rv), _ = faults_mod.degrade_gathered(
-            rr, (Rf, Ri, Rv), jnp.zeros((), jnp.int32))
+        # ---- round 2: the (Q, J, cap) survivor stack, in lane blocks ---
+        Q, J = taus_q.shape
+        block = Q * J if cfg.faults is not None else rr.lane_block(Q * J)
+        if block == Q * J:
+            # (Q, J, m*f_cap, d) in one gather; phase 2 runs in phase_b
+            Rf, Ri, Rv = rounds.gather_lanes((rf, ri, rv), gather_axes,
+                                             lead=2)
+            (Rf, Ri, Rv), _ = faults_mod.degrade_gathered(
+                rr, (Rf, Ri, Rv), jnp.zeros((), jnp.int32))
+        else:
+            def lane_accept(c, cand, a):
+                kq, lam, alpha, tau = a
+                st, sol, size, _ = rounds.greedy_step(
+                    bind_query(oracle, lam, alpha), (*c, ()), cand, tau, K,
+                    cfg, k_dyn=kq)
+                return st, sol, size
+
+            def lanes(x):
+                return x.reshape((Q * J,) + x.shape[2:])
+
+            st, sol, size = rounds.gather_accept(
+                tuple(map(lanes, (rf, ri, rv))),
+                tuple(map(lanes, (st_q, sol_q, size_q))),
+                (jnp.repeat(qk, J), jnp.repeat(qlam, J),
+                 jnp.repeat(qalpha, J), lanes(taus_q)),
+                lane_accept, gather_axes, block)
+            st_q, sol_q, size_q = (x.reshape((Q, J) + x.shape[1:])
+                                   for x in (st, sol, size))
+            Rf = Ri = Rv = None
 
         # ---- central phase 2 + sparse path, per query -------------------
         def phase_b(kq, lam, alpha, taus, st_j, sol_j, size_j, f_j, i_j, v_j,
                     ltf, lti, ltv):
             orc = bind_query(oracle, lam, alpha)
             return _query_grid_b(orc, cfg, K, kq, taus,
-                                 (st_j, sol_j, size_j), (f_j, i_j, v_j),
+                                 (st_j, sol_j, size_j),
+                                 None if f_j is None else (f_j, i_j, v_j),
                                  (ltf, lti, ltv),
                                  v_sparse if shared_stats else None)
 

@@ -22,6 +22,11 @@ is 1 epoch vmapped over the unknown-OPT tau grid, and the paper's
 (1 - 1/e - eps) multi-epoch driver is E = ceil(1/eps) epochs over the
 same grid.
 
+On the mesh a grid epoch's survivor gather and the accept after it run
+over the threshold lanes in blocks of ceil(J/m), one block after another
+(``gather_accept``), so that a device holds one block of the gathered
+survivor stack, not all J lanes of every machine's buffer.
+
 Each primitive, here and in ``core/threshold.py``, traces under a
 ``jax.named_scope`` named for its move (``sample``, ``tops``, ``filter``,
 ``pack``, ``gather``, ``accept``), so every device op of every algorithm
@@ -37,6 +42,7 @@ Lemma-2/Lemma-6 memory bounds are checkable quantities, not comments.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, List
 
 import jax
@@ -257,6 +263,34 @@ def local_top(oracle, feats, ids, valid, cap, constraint=None):
     return f, i, v, jnp.zeros((), jnp.int32)
 
 
+#: The gathers of the last mesh selection program built, counted while it
+#: traces (each ``MeshRounds`` starts a new count): ``lane_blocks`` blocks
+#: of ``block_lanes`` lanes per grid survivor gather (``gather_accept``),
+#: and ``bytes_in``, what one device receives from the other machines per
+#: selection over all of the program's gathers.
+_GATHER = {"lane_blocks": 0, "block_lanes": 0, "bytes_in": 0}
+
+
+def gather_stats() -> dict:
+    """The trace-time gather counts of the last mesh program built."""
+    return dict(_GATHER)
+
+
+def count_gathered(xs, gather_axes, times: int = 1) -> None:
+    """Count what one device receives when each of ``xs`` is all-gathered
+    ``times`` times: the other machines' buffers."""
+    m = jax.lax.axis_size(gather_axes)
+    _GATHER["bytes_in"] += times * (m - 1) * sum(
+        math.prod(x.shape) * jnp.dtype(x.dtype).itemsize for x in xs)
+
+
+def block_lanes(lanes: int, m: int) -> int:
+    """Lanes per block of a grid survivor gather over m machines,
+    ceil(lanes / m): one block of the gathered stack is about what one
+    machine's own survivor buffers take."""
+    return -(-lanes // m)
+
+
 @jax.named_scope("gather")
 def gather_packed(x, gather_axes, lead: int = 0):
     """all_gather a packed message buffer inside a shard_map body,
@@ -266,6 +300,64 @@ def gather_packed(x, gather_axes, lead: int = 0):
     collective, concatenated on the capacity axis itself, so no second,
     transposed copy of the gathered stack is ever made."""
     return jax.lax.all_gather(x, gather_axes, axis=lead, tiled=True)
+
+
+def gather_accept(local, carry, lane_args, accept, gather_axes, block):
+    """The survivor round's gather and central accept over the lane axis
+    in blocks of ``block`` lanes, one block after another, so that no
+    device ever holds more than one block of the gathered stack.
+
+    ``local`` is this machine's packed triple, each (L, cap, ...) for L
+    lanes; ``carry`` and ``lane_args`` are pytrees with a leading (L,)
+    axis; ``accept(carry, cands, args)`` is one lane's accept and returns
+    that lane's carry.  The blocks are the steps of a ``lax.scan``, so each
+    block's all-gather waits for the block before it.  They are equal: the
+    last one starts at L - block and overlaps the one before it, and an
+    overlapped lane is accepted again from the same carry and candidates,
+    so it is written twice with the same values.  Each lane sees the same
+    candidates in the same order as in one gather of the whole stack, so
+    the answers are the same bit for bit."""
+    L = local[1].shape[0]
+    n_blocks = -(-L // block)
+    _GATHER.update(lane_blocks=n_blocks, block_lanes=block)
+    count_gathered([jax.ShapeDtypeStruct((block,) + x.shape[1:], x.dtype)
+                    for x in local], gather_axes, times=n_blocks)
+
+    def take(tree, start):
+        return jax.tree.map(
+            lambda x: jax.lax.dynamic_slice_in_dim(x, start, block), tree)
+
+    def one_block(out, start):
+        with jax.named_scope("gather"):
+            cands = tuple(gather_packed(x, gather_axes, lead=1)
+                          for x in take(local, start))
+        new = jax.vmap(accept)(take(carry, start), cands,
+                               take(lane_args, start))
+        out = jax.tree.map(
+            lambda o, n: jax.lax.dynamic_update_slice_in_dim(o, n, start, 0),
+            out, new)
+        return out, None
+
+    starts = jnp.minimum(jnp.arange(n_blocks, dtype=jnp.int32) * block,
+                         L - block)
+    out, _ = jax.lax.scan(one_block, carry, starts)
+    return out
+
+
+def gather_lanes(local, gather_axes, lead: int = 1):
+    """All-gather a packed triple whose ``lead`` leading axes are lanes,
+    the whole stack in one block."""
+    _GATHER.update(lane_blocks=1, block_lanes=math.prod(
+        local[1].shape[:lead]))
+    count_gathered(local, gather_axes)
+    return tuple(gather_packed(x, gather_axes, lead=lead) for x in local)
+
+
+def filter_accept_gathered(rr, oracle, carry, taus, cap, k, chunk, accept):
+    """A grid epoch's survivor round in one block: ``rr.filter_grid``'s
+    whole gathered (J, m*cap) stack, then every lane's accept on it."""
+    R, rdrop = rr.filter_grid(oracle, *carry, taus, cap, k, chunk)
+    return jax.vmap(accept)(carry, R, taus), rdrop
 
 
 # ---------------------------------------------------------------------------
@@ -350,6 +442,11 @@ class SimRounds:
         rv = rv.transpose(1, 0, 2).reshape(J, m * cap)
         return (rf, ri, rv), jnp.sum(rdrop)
 
+    def filter_accept_grid(self, oracle, carry, taus, cap, k, chunk, accept):
+        """filter_grid, then ``accept`` on every lane: one block."""
+        return filter_accept_gathered(self, oracle, carry, taus, cap, k,
+                                      chunk, accept)
+
     def finalize_drops(self, drops):
         return drops
 
@@ -360,7 +457,7 @@ class MeshRounds:
     counts stay machine-local until ``finalize_drops`` psums them once."""
 
     def __init__(self, oracle, feats, ids, valid, gather_axes,
-                 precision=None, constraint=None):
+                 precision=None, constraint=None, _block_lanes=None):
         self.oracle = oracle
         if precision is not None:
             feats = precision.cast_storage(feats)
@@ -369,11 +466,20 @@ class MeshRounds:
         self.feats, self.ids, self.valid = feats, ids, valid
         self.gather_axes = gather_axes
         self.machine_index = jax.lax.axis_index(gather_axes)
+        self.m = jax.lax.axis_size(gather_axes)
+        # lanes per block of the grid survivor gather; None derives
+        # block_lanes(J, m) (tests set it to compare blockings)
+        self._block_lanes = _block_lanes
+        _GATHER.update(lane_blocks=0, block_lanes=0, bytes_in=0)
+
+    def lane_block(self, lanes: int) -> int:
+        return min(lanes, self._block_lanes or block_lanes(lanes, self.m))
 
     def begin_epoch(self, e: int) -> None:
         """Epoch-boundary hook — see SimRounds.begin_epoch."""
 
     def _gather3(self, f, i, v, lead: int = 0):
+        count_gathered((f, i, v), self.gather_axes)
         return tuple(gather_packed(x, self.gather_axes, lead=lead)
                      for x in (f, i, v))
 
@@ -397,14 +503,35 @@ class MeshRounds:
                                          cstate=cstate)
         return self._gather3(rf, ri, rv), rdrop
 
-    def filter_grid(self, oracle, st_j, sol_j, size_j, cstate_j, taus, cap,
-                    k, chunk):
-        rf, ri, rv, rdrop = jax.vmap(
+    def _filter_lanes(self, oracle, st_j, sol_j, size_j, cstate_j, taus,
+                      cap, k, chunk):
+        return jax.vmap(
             lambda st, sol, size, cst, tau: local_filter(
                 oracle, st, sol, self.feats, self.ids, self.valid, tau, cap,
                 size, k, chunk, constraint=self.constraint, cstate=cst)
         )(st_j, sol_j, size_j, cstate_j, taus)
+
+    def filter_grid(self, oracle, st_j, sol_j, size_j, cstate_j, taus, cap,
+                    k, chunk):
+        rf, ri, rv, rdrop = self._filter_lanes(oracle, st_j, sol_j, size_j,
+                                               cstate_j, taus, cap, k, chunk)
         return self._gather3(rf, ri, rv, lead=1), jnp.sum(rdrop)
+
+    def filter_accept_grid(self, oracle, carry, taus, cap, k, chunk, accept):
+        """The grid survivor round: every lane's filter in one call, then
+        the gather and ``accept`` in blocks of ``lane_block(J)`` lanes
+        (``gather_accept``); one block is filter_grid's whole stack."""
+        J = taus.shape[0]
+        block = self.lane_block(J)
+        if block == J:
+            _GATHER.update(lane_blocks=1, block_lanes=J)
+            return filter_accept_gathered(self, oracle, carry, taus, cap, k,
+                                          chunk, accept)
+        rf, ri, rv, rdrop = self._filter_lanes(oracle, *carry, taus, cap, k,
+                                               chunk)
+        carry = gather_accept((rf, ri, rv), carry, taus, accept,
+                              self.gather_axes, block)
+        return carry, jnp.sum(rdrop)
 
     def finalize_drops(self, drops):
         return jax.lax.psum(drops, self.gather_axes)
@@ -534,12 +661,10 @@ def run_epochs(oracle, rounds, schedule, epoch_keys, cfg, k_dyn=None,
                     lambda c, t: greedy_step(oracle, c, S, t, k, cfg, k_dyn,
                                              constraint)
                 )(carry, taus)
-            R, rdrop = rounds.filter_grid(oracle, *carry, taus, f_cap, keff,
-                                          cfg.filter_chunk)
-            carry = jax.vmap(
+            carry, rdrop = rounds.filter_accept_grid(
+                oracle, carry, taus, f_cap, keff, cfg.filter_chunk,
                 lambda c, cand, t: greedy_step(oracle, c, cand, t, k, cfg,
-                                               k_dyn, constraint)
-            )(carry, R, taus)
+                                               k_dyn, constraint))
         else:
             if carry is None:
                 carry = empty_solution(oracle, k, constraint)

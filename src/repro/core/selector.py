@@ -299,9 +299,12 @@ class DistributedSelector:
         degraded_selects, ...) summed across every select()/select_batch()
         this selector served — the single-query round log plus every
         slot-width batch log — merged with the fault-injection records
-        (``fault_*`` keys, from RoundLog.fault_events()) and with the
+        (``fault_*`` keys, from RoundLog.fault_events()), with the
         coverage filter's route counts of the process (``marginals_*``,
-        kernels.coverage_marginals.lane_stats).  This is the one
+        kernels.coverage_marginals.lane_stats) and with the survivor
+        gather of the last mesh program built (``gather_lane_blocks``,
+        ``gather_block_lanes``, ``gather_bytes_in``;
+        rounds.gather_stats).  This is the one
         place the lazy device scalars are forced to ints, so serving
         stats/SLO dashboards read one dict instead of reaching into per-Q
         RoundLogs."""
@@ -329,6 +332,14 @@ class DistributedSelector:
             out["marginals_fused_calls"] = lanes["fused_calls"]
             out["marginals_fused_lanes"] = lanes["fused_lanes"]
             out["marginals_per_lane_calls"] = lanes["per_lane_calls"]
+        # the grid survivor gather of the last mesh program built
+        # (trace-time): blocks, lanes a block, bytes one device receives
+        # per selection (rounds.gather_stats)
+        from repro.core.rounds import gather_stats
+        gathers = gather_stats()
+        if gathers["lane_blocks"]:
+            for name, v in gathers.items():
+                out[f"gather_{name}"] = v
         return out
 
     def opt_upper_bound(self, embeddings) -> jax.Array:
