@@ -300,8 +300,9 @@ class DistributedSelector:
         this selector served — the single-query round log plus every
         slot-width batch log — merged with the fault-injection records
         (``fault_*`` keys, from RoundLog.fault_events()), with the
-        coverage filter's route counts of the process (``marginals_*``,
-        kernels.coverage_marginals.lane_stats) and with the survivor
+        coverage filter's and central accept's route counts of the process
+        (``marginals_*``, kernels.coverage_marginals.lane_stats;
+        ``accept_*``, kernels.coverage_accept.lane_stats) and with the survivor
         gather of the last mesh program built (``gather_lane_blocks``,
         ``gather_block_lanes``, ``gather_bytes_in``;
         rounds.gather_stats).  This is the one
@@ -324,14 +325,15 @@ class DistributedSelector:
                     out[key] = min(out.get(key, v), v)
                 else:
                     out[key] = out.get(key, 0) + v
-        # the coverage filter's route in each program built so far in this
-        # process (trace-time counts, not per select)
-        from repro.kernels.coverage_marginals import lane_stats
-        lanes = lane_stats()
-        if lanes["fused_calls"] or lanes["per_lane_calls"]:
-            out["marginals_fused_calls"] = lanes["fused_calls"]
-            out["marginals_fused_lanes"] = lanes["fused_lanes"]
-            out["marginals_per_lane_calls"] = lanes["per_lane_calls"]
+        # the coverage filter's and accept's routes in each program built
+        # so far in this process (trace-time counts, not per select)
+        from repro.kernels import coverage_accept, coverage_marginals
+        for prefix, module in (("marginals", coverage_marginals),
+                               ("accept", coverage_accept)):
+            lanes = module.lane_stats()
+            if lanes["fused_calls"] or lanes["per_lane_calls"]:
+                for name, v in lanes.items():
+                    out[f"{prefix}_{name}"] = v
         # the grid survivor gather of the last mesh program built
         # (trace-time): blocks, lanes a block, bytes one device receives
         # per selection (rounds.gather_stats)

@@ -32,6 +32,14 @@ at the end — no dynamic vector stores.  Candidate rows are read with a
 dynamic sublane index, which the TPU compiler only accepts on an unpacked
 32-bit tile, so a bf16 tile is upcast once into f32 scratch
 (:func:`f32_rows`).
+
+Many lanes at once.  A vmap over threshold lanes hands the sweep L
+independent (candidates, state, tau, budget) problems.
+:func:`run_lane_sweep` sweeps :data:`LANE_GROUP` of them together, one
+lane per f32 sublane: row i of all eight lanes is one (8, dp) tile, each
+lane's gain is a per-sublane sum, and the accept test, the state update
+and the per-row outputs are per-sublane selects, so no value goes to the
+scalar unit and the eight dependency chains do not wait on one another.
 """
 
 from __future__ import annotations
@@ -105,6 +113,55 @@ def run_sweep(nrows: int, elig_ref, tau_ref, budget_ref, mask_ref,
     mask_ref[...] = mask
     gains_ref[...] = gains
     state_out_ref[...] = st_scratch[...]
+
+
+#: lanes a many-lane sweep carries at once, one per f32 sublane
+LANE_GROUP = 8
+
+
+def run_lane_sweep(row0, nrows: int, elig_ref, tau_ref, budget_ref,
+                   mask_ref, gains_ref, st_scratch, n_scratch, row_fn,
+                   step_fn):
+    """Rows ``row0 .. row0 + nrows - 1`` (``row0`` may be traced) of the
+    accept sweep for :data:`LANE_GROUP` lanes at once, one lane per
+    sublane.
+
+    The carry lives in refs, so a sweep may run in row blocks: the
+    (G, dp) states in ``st_scratch``, the (G, 1) accept counts in
+    ``n_scratch``, and the (G, Bp) mask and gains rows in ``mask_ref`` /
+    ``gains_ref``, all zeroed or loaded by the caller before the first
+    block.  ``elig_ref`` is (G, Bp) int32, ``tau_ref`` (G, 1) f32 and
+    ``budget_ref`` (G, 1) int32, a lane's values on its sublane.
+
+        row_fn(i)         -> the (G, dp) f32 rows at block offset i
+        step_fn(st, rows) -> (gain (G, 1), new_state (G, dp))
+
+    Each sublane runs :func:`run_sweep`'s cardinality sweep (no knapsack
+    carry): the same gain, the same accept test, the same update."""
+    Bp = mask_ref.shape[1]
+    tau = tau_ref[...]
+    budget = budget_ref[...]
+    elig = elig_ref[...]
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (1, Bp), 1)
+
+    def body(i, carry):
+        n_acc, mask, gains = carry
+        rows = row_fn(i)
+        st = st_scratch[...]
+        gain, new_st = step_fn(st, rows)
+        here = row_iota == row0 + i
+        ok = jnp.sum(jnp.where(here, elig, 0), axis=1, keepdims=True) > 0
+        acc = ok & (gain >= tau) & (n_acc < budget)          # (G, 1)
+        st_scratch[...] = jnp.where(acc, new_st, st)
+        mask = jnp.where(here, acc.astype(jnp.int32), mask)
+        gains = jnp.where(here, gain, gains)
+        return n_acc + acc.astype(jnp.int32), mask, gains
+
+    n_acc, mask, gains = jax.lax.fori_loop(
+        0, nrows, body, (n_scratch[...], mask_ref[...], gains_ref[...]))
+    n_scratch[...] = n_acc
+    mask_ref[...] = mask
+    gains_ref[...] = gains
 
 
 def row_operands(n: int, eligible, tau, budget, cost=None,

@@ -67,3 +67,27 @@ def mxu_params():
     f32-precision matmul splits them into, overflow the TPU compiler's
     default 16 MiB of scoped VMEM; a v5e core has 128 MiB."""
     return pltpu.CompilerParams(vmem_limit_bytes=64 * 2**20)
+
+
+# ---------------------------------------------------------------------------
+# vmap rules of the many-lane kernels (jax.custom_batching.custom_vmap)
+# ---------------------------------------------------------------------------
+
+def all_batched(out):
+    """Every leaf of a rule's output batched on axis 0."""
+    return jax.tree.map(lambda _: True, out)
+
+
+def unbatch(fn, in_batched, args):
+    """Call ``fn`` on an axis of size 1 as on no axis."""
+    args = [a[0] if b else a for a, b in zip(args, in_batched)]
+    out = fn(*args)
+    return jax.tree.map(lambda o: o[None], out), all_batched(out)
+
+
+def pallas_vmap(fn, in_batched, args):
+    """``fn`` under jax.vmap: a pallas_call gets Pallas's own batching, an
+    outer grid axis."""
+    axes = [0 if b else None for b in in_batched]
+    out = jax.vmap(fn, in_axes=axes)(*args)
+    return out, all_batched(out)

@@ -37,7 +37,9 @@ from repro.kernels._tiling import ceil_to as _ceil_to
 from repro.kernels._tiling import gains_out as _gains_out
 from repro.kernels._tiling import row_block as _row_block
 from repro.kernels._tiling import pad_axis as _pad_axis
+from repro.kernels._tiling import pallas_vmap as _pallas_vmap
 from repro.kernels._tiling import sublane as _sublane
+from repro.kernels._tiling import unbatch as _unbatch
 
 DEFAULT_BC = 256
 DEFAULT_BF = 512
@@ -188,17 +190,6 @@ def lane_stats() -> dict:
     (the candidate block or the weights differ along the axis)."""
     return {k: _STATS[k]
             for k in ("fused_calls", "fused_lanes", "per_lane_calls")}
-
-
-def _unbatch(fn, in_batched, args):
-    """Call ``fn`` on an axis of size 1 as on no axis."""
-    args = [a[0] if b else a for a, b in zip(args, in_batched)]
-    return fn(*args)[None], True
-
-
-def _pallas_vmap(fn, in_batched, args):
-    axes = [0 if b else None for b in in_batched]
-    return jax.vmap(fn, in_axes=axes)(*args), True
 
 
 @functools.cache

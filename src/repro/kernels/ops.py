@@ -127,10 +127,11 @@ def coverage_accept(x, state, weights, eligible, tau, budget,
     """Fused FeatureCoverage chunk-accept sweep: one kernel runs the
     ThresholdGreedy inner loop over the (B, d) tile.  Returns
     (mask (B,) bool, state (d,), gains (B,)).  ``cost``/``cost_budget``
-    switch to knapsack cost-ratio accepts (all accept entries)."""
-    return _ca.coverage_accept(x, state, weights, eligible, tau, budget,
-                               interpret=_interpret(), cost=cost,
-                               cost_budget=cost_budget)
+    switch to knapsack cost-ratio accepts (all accept entries).  Under a
+    vmap over the lanes (the threshold guesses; weights shared, no cost)
+    eight lanes sweep at once (``coverage_accept.routed``)."""
+    return _ca.routed(_interpret())(x, state, weights, eligible, tau,
+                                    budget, cost, cost_budget)
 
 
 def weighted_coverage_accept(x, state, eligible, tau, budget,

@@ -238,10 +238,13 @@ def test_coverage_marginals_lanes_bit_exact(C, d, L):
                                               x, states, w)))
 
 
-def _stats_delta(fn):
-    before = cm.lane_stats()
+def _stats_delta(fn, stats=None):
+    """``fn()`` and how far it moved a route's counts (the marginals'
+    unless ``stats`` says otherwise)."""
+    stats = stats or cm.lane_stats
+    before = stats()
     out = fn()
-    after = cm.lane_stats()
+    after = stats()
     return out, {k: after[k] - before[k] for k in after}
 
 
@@ -324,9 +327,9 @@ def _per_lane_route(x, state, weights=None, **_):
     return coverage_marginals(x, state, weights, interpret=True)
 
 
-@pytest.mark.parametrize("driver", ["two_round", "two_round_batch",
+@pytest.mark.parametrize("entry", ["two_round", "two_round_batch",
                                     "selector", "selector_batch"])
-def test_coverage_lane_fusion_end_to_end(driver, monkeypatch):
+def test_coverage_lane_fusion_end_to_end(entry, monkeypatch):
     """Selections with use_kernel=True return the same ids and values with
     the many-lane filter as with every lane read apart."""
     from repro.core import (DistributedSelector, FeatureCoverage, MRConfig,
@@ -344,10 +347,10 @@ def test_coverage_lane_fusion_end_to_end(driver, monkeypatch):
               jnp.ones((m, n // m), bool))
 
     def select():
-        if driver == "two_round":
+        if entry == "two_round":
             return jax.jit(lambda *a: two_round_sim(
                 oracle, *a, cfg, key)[0])(*shards)
-        if driver == "two_round_batch":
+        if entry == "two_round_batch":
             return jax.jit(lambda *a: two_round_batch_sim(
                 oracle, *a, qb, cfg, key)[0])(*shards)
         mesh = make_mesh_for(len(jax.devices()), model_parallel=1)
@@ -355,7 +358,7 @@ def test_coverage_lane_fusion_end_to_end(driver, monkeypatch):
                             use_kernel=True)
         sel = DistributedSelector(spec, mesh, n_total=n, feat_dim=d)
         sels.append(sel)
-        if driver == "selector":
+        if entry == "selector":
             return sel.select(X, key=key)
         return sel.select_batch(X, qb, key=key)
 
@@ -828,6 +831,196 @@ def test_accept_scan_vs_kernel_property(B, d, seed, budget, tau_scale):
                           interpret=True)
     want = ref.coverage_accept(x, state, None, elig, tau, budget)
     _assert_accept_matches(got, want, d, jnp.float32, "coverage_accept")
+
+
+# ---------------------------------------------------------------------------
+# many-lane coverage accept: eight lanes a sweep, one per sublane
+# ---------------------------------------------------------------------------
+
+from repro.kernels import coverage_accept as ca  # noqa: E402
+
+K_LANES = 8     # the cardinality k of the lane cases
+
+
+def _accept_lanes_case(L, B, d, dtype, weighted=True):
+    """L lanes of (x, state, eligible, tau, budget); tau per lane from its
+    own gain scale, budgets cycling through k, 0, 3, B and 1, and lane
+    2 % L with no eligible row."""
+    k1, k2, k3, k4 = jax.random.split(jax.random.PRNGKey(L * 101 + B + d), 4)
+    x = jnp.abs(_rand(k1, (L, B, d), dtype))
+    states = jnp.abs(_rand(k2, (L, d), jnp.float32)) * 3.0
+    w = jnp.abs(_rand(k3, (d,), jnp.float32)) if weighted else None
+    elig = jax.random.uniform(k4, (L, B)) < 0.7
+    if L > 1:
+        elig = elig.at[2 % L].set(False)
+    gains = jax.vmap(lambda xl, sl: ref.coverage_marginals(xl, sl, w))(
+        x, states)
+    scale = jnp.linspace(0.6, 1.4, L)
+    tau = jnp.median(gains, axis=1) * scale
+    budget = jnp.asarray([(K_LANES, 0, 3, B, 1)[l % 5] for l in range(L)],
+                         jnp.int32)
+    return x, states, w, elig, tau, budget
+
+
+def _accept_lane_by_lane(x, states, w, elig, tau, budget, cost=None,
+                         cost_budget=None):
+    """The one-lane kernel once per lane (a lax.map, no vmap rule)."""
+    xs = (x if x.ndim == 3 else jnp.broadcast_to(x, (states.shape[0],
+                                                     *x.shape)))
+    per = [xs, states, elig, tau, budget]
+    if cost is not None:
+        per += [cost, cost_budget]
+
+    def one(a):
+        c, cb = (a[5], a[6]) if cost is not None else (None, None)
+        return ca.coverage_accept(a[0], a[1], w, a[2], a[3], a[4],
+                                  interpret=True, cost=c, cost_budget=cb)
+    return jax.lax.map(one, per)
+
+
+def _assert_bits(got, want):
+    for g, w_, name in zip(got, want, ("mask", "state", "gains")):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w_),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=["f32", "bf16"])
+@pytest.mark.parametrize("B,d", [(5, 100), (5, 3_072), (128, 100),
+                                 (128, 3_072)])
+@pytest.mark.parametrize("L", [1, 7, 8, 9, 37])
+def test_coverage_accept_lanes_bit_exact(L, B, d, dtype):
+    """Each lane of the many-lane sweep returns the one-lane kernel's mask,
+    state and gains bit for bit: ragged lane groups, ragged rows and
+    features, bf16 storage, budgets 0 and k, a lane with nothing
+    eligible."""
+    x, states, w, elig, tau, budget = _accept_lanes_case(L, B, d, dtype)
+    got = ca.coverage_accept_lanes(x, states, w, elig, tau, budget,
+                                   interpret=True)
+    assert got[0].shape == (L, B) and got[1].shape == (L, d)
+    want = _accept_lane_by_lane(x, states, w, elig, tau, budget)
+    _assert_bits(got, want)
+    mask = np.asarray(got[0])
+    assert not mask[np.asarray(budget) == 0].any()
+    if L > 1:
+        assert not mask[2 % L].any()
+    assert mask.any()
+
+
+@pytest.mark.parametrize("batched", ["state", "all_but_weights", "nested",
+                                     "size1", "cost"])
+def test_coverage_accept_vmap_routing(batched):
+    """A vmap over the state, with or without the other per-lane operands,
+    runs one many-lane sweep; nested vmaps fold into it; an axis of size 1
+    is no axis; a knapsack sweep keeps Pallas's per-lane batching.  Each
+    gives the one-lane kernel's bits."""
+    L, B, d = 6, 40, 96
+    x, states, w, elig, tau, budget = _accept_lanes_case(L, B, d,
+                                                         jnp.float32)
+    cost = jnp.abs(_rand(jax.random.PRNGKey(1), (L, B), jnp.float32)) + 0.1
+    cbud = jnp.full((L,), 2.5, jnp.float32)
+    acc = ops.coverage_accept
+    fused = {"fused_calls": 1, "fused_lanes": L, "per_lane_calls": 0}
+    if batched == "state":
+        def fn():
+            return jax.vmap(lambda s: acc(x[0], s, w, elig[0], tau[0],
+                                          budget[0]))(states)
+        want = _accept_lane_by_lane(x[0], states, w,
+                                    jnp.broadcast_to(elig[0], (L, B)),
+                                    jnp.broadcast_to(tau[0], (L,)),
+                                    jnp.broadcast_to(budget[0], (L,)))
+    elif batched == "all_but_weights":
+        def fn():
+            return jax.vmap(lambda *a: acc(a[0], a[1], w, *a[2:]))(
+                x, states, elig, tau, budget)
+        want = _accept_lane_by_lane(x, states, w, elig, tau, budget)
+    elif batched == "nested":
+        # queries x lanes, the taus shared by the queries: one call of 2L
+        Q = 2
+        xq = jnp.stack([x, x * 1.5])
+        sq = jnp.stack([states, states * 0.5])
+
+        def fn():
+            lanes = lambda xx, ss, tt: jax.vmap(
+                lambda a, b, c, e, f: acc(a, b, w, c, e, f))(
+                    xx, ss, elig, tt, budget)
+            return jax.vmap(lanes, in_axes=(0, 0, None))(xq, sq, tau)
+        want = [jnp.stack(z) for z in zip(*[
+            _accept_lane_by_lane(xq[q], sq[q], w, elig, tau, budget)
+            for q in range(Q)])]
+        fused = dict(fused, fused_lanes=Q * L)
+    elif batched == "size1":
+        def fn():
+            return jax.vmap(lambda *a: acc(a[0], a[1], w, *a[2:]))(
+                x[:1], states[:1], elig[:1], tau[:1], budget[:1])
+        want = _accept_lane_by_lane(x[:1], states[:1], w, elig[:1], tau[:1],
+                                    budget[:1])
+        fused = {"fused_calls": 0, "fused_lanes": 0, "per_lane_calls": 0}
+    else:
+        def fn():
+            return jax.vmap(lambda *a: acc(a[0], a[1], w, *a[2:5],
+                                           cost=a[5], cost_budget=a[6]))(
+                x, states, elig, tau, budget, cost, cbud)
+        want = _accept_lane_by_lane(x, states, w, elig, tau, budget, cost,
+                                    cbud)
+        fused = {"fused_calls": 0, "fused_lanes": 0, "per_lane_calls": 1}
+    got, delta = _stats_delta(lambda: jax.jit(fn)(), ca.lane_stats)
+    _assert_bits(got, want)
+    assert delta == fused
+
+
+@pytest.mark.parametrize("entry", ["two_round", "two_round_batch",
+                                    "selector"])
+def test_coverage_accept_fusion_end_to_end(entry, monkeypatch):
+    """Selections with use_kernel=True return the same ids and values with
+    the many-lane accept as with every lane swept apart, and the selector's
+    runtime events count the route: fused, never per lane."""
+    import collections
+    from repro.core import (DistributedSelector, FeatureCoverage, MRConfig,
+                            SelectorSpec, make_query_batch, two_round_sim,
+                            two_round_batch_sim)
+    from repro.launch.mesh import make_mesh_for
+    n, d, k, m = 512, 48, 8, 2
+    X = _grouped_corpus(4, n, d)
+    key = jax.random.PRNGKey(6)
+    qb = make_query_batch([k, k // 2])
+    oracle = FeatureCoverage(feat_dim=d, use_kernel=True)
+    cfg = MRConfig(k=k, n_total=n, n_machines=m, engine="fused")
+    shards = (X.reshape(m, n // m, d),
+              jnp.arange(n, dtype=jnp.int32).reshape(m, n // m),
+              jnp.ones((m, n // m), bool))
+    events = []
+
+    def select():
+        if entry == "two_round":
+            return jax.jit(lambda *a: two_round_sim(
+                oracle, *a, cfg, key)[0])(*shards)
+        if entry == "two_round_batch":
+            return jax.jit(lambda *a: two_round_batch_sim(
+                oracle, *a, qb, cfg, key)[0])(*shards)
+        mesh = make_mesh_for(len(jax.devices()), model_parallel=1)
+        spec = SelectorSpec(k=k, oracle="feature_coverage", engine="fused",
+                            use_kernel=True)
+        sel = DistributedSelector(spec, mesh, n_total=n, feat_dim=d)
+        out = sel.select(X, key=key)
+        events.append(sel.runtime_events())
+        return out
+
+    monkeypatch.setattr(ca, "_STATS", collections.Counter())
+    fused, delta = _stats_delta(select, ca.lane_stats)
+    assert delta["fused_calls"] >= 1 and delta["per_lane_calls"] == 0
+    if events:
+        assert events[0]["accept_fused_calls"] == delta["fused_calls"]
+        assert events[0]["accept_fused_lanes"] == delta["fused_lanes"] > 0
+        assert events[0]["accept_per_lane_calls"] == 0
+    monkeypatch.setattr(
+        ops, "coverage_accept",
+        lambda *a, **kw: ca.coverage_accept(*a, interpret=True, **kw))
+    apart, delta = _stats_delta(select, ca.lane_stats)
+    assert delta["fused_calls"] == 0
+    np.testing.assert_array_equal(np.asarray(fused.sol_ids),
+                                  np.asarray(apart.sol_ids))
+    np.testing.assert_array_equal(np.asarray(fused.value),
+                                  np.asarray(apart.value))
 
 
 # ---------------------------------------------------------------------------

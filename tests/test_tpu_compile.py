@@ -113,6 +113,13 @@ MARGINALS = {
 
 _SWEEP = [(jnp.bool_, (B,)), (F32, ()), (I32, ())]   # eligible, tau, budget
 
+
+def _lane_sweep(lanes):
+    """Operands of a many-lane accept: candidates, states, eligibility,
+    tau and budget, one per lane."""
+    return [("X", (lanes, B, D)), (F32, (lanes, D)), (jnp.bool_, (lanes, B)),
+            (F32, (lanes,)), (I32, (lanes,))]
+
 ACCEPTS = {
     "coverage": (lambda x, s, e, t, b: ca.coverage_accept(x, s, None, e, t, b),
                  [("X", (B, D)), (F32, (D,))] + _SWEEP),
@@ -120,6 +127,18 @@ ACCEPTS = {
         lambda x, s, e, t, b, c, cb: ca.coverage_accept(
             x, s, None, e, t, b, cost=c, cost_budget=cb),
         [("X", (B, D)), (F32, (D,))] + _SWEEP + [(F32, (B,)), (F32, ())]),
+    # the threshold lanes of one selection, and of an 8-slot served batch
+    "coverage_lanes37": (
+        lambda x, s, e, t, b: ca.coverage_accept_lanes(x, s, None, e, t, b),
+        _lane_sweep(J)),
+    "coverage_lanes296": (
+        lambda x, s, e, t, b: ca.coverage_accept_lanes(x, s, None, e, t, b),
+        _lane_sweep(8 * J)),
+    "coverage_vmapped_lanes": (
+        lambda x, s, e, t, b: jax.vmap(
+            lambda *a: ca.routed(False)(*a[:2], None, *a[2:], None, None))(
+                x, s, e, t, b),
+        _lane_sweep(J)),
     "saturated_coverage": (
         lambda x, s, c, e, t, b: sa.saturated_coverage_accept(
             x, s, c, None, e, t, b),
